@@ -4,7 +4,8 @@ No offline dataset ships with this container, so 'trained weights' come
 from training on the procedural glyph task (repro.data.glyph_batch) - what
 matters for the paper's BT statistics is the post-training weight
 distribution (concentrated, near-zero-heavy), not the dataset identity.
-Weights are cached under experiments/weights/.
+The trained weights are committed under experiments/weights/; loading
+them never retrains (``train_and_save`` is the explicit rebuild).
 """
 from __future__ import annotations
 
@@ -41,22 +42,39 @@ def _train(model, channels: int, steps: int, seed: int = 0, batch: int = 64):
     return st.params, float(m["loss"]), acc
 
 
-def get_trained(name: str, steps: int = 400):
-    """name in {lenet, darknet}; returns (model, trained params, accuracy)."""
-    model = LeNet() if name == "lenet" else DarkNetLike()
-    channels = model.input_shape[-1]
+def _model(name: str):
+    return LeNet() if name == "lenet" else DarkNetLike()
+
+
+def get_trained(name: str):
+    """name in {lenet, darknet}; returns (model, trained params, accuracy)
+    restored from the committed checkpoint under ``experiments/weights``.
+
+    Raises FileNotFoundError when the checkpoint is missing or does not
+    restore; it never retrains. :func:`train_and_save` rebuilds it.
+    """
+    model = _model(name)
     ckpt_dir = os.path.join(CACHE, name)
     ref = init_params(model.specs(), jax.random.PRNGKey(0))
     got = checkpoint.restore(ckpt_dir, {"params": ref, "acc": jnp.zeros(())})
-    if got is not None:
-        blob = got[1]
-        return model, blob["params"], float(blob["acc"])
-    params, loss, acc = _train(model, channels, steps)
-    checkpoint.save(ckpt_dir, steps, {"params": params,
-                                      "acc": jnp.asarray(acc)})
+    if got is None:
+        raise FileNotFoundError(
+            f"no restorable {name} checkpoint under {os.path.abspath(ckpt_dir)}"
+            f"; rebuild it with benchmarks._trained.train_and_save({name!r})")
+    blob = got[1]
+    return model, blob["params"], float(blob["acc"])
+
+
+def train_and_save(name: str, steps: int = 400):
+    """Train ``name`` on the glyph task and overwrite its checkpoint;
+    returns (model, params, accuracy)."""
+    model = _model(name)
+    params, _, acc = _train(model, model.input_shape[-1], steps)
+    checkpoint.save(os.path.join(CACHE, name), steps,
+                    {"params": params, "acc": jnp.asarray(acc)})
     return model, params, acc
 
 
 def random_params(name: str, seed: int = 1):
-    model = LeNet() if name == "lenet" else DarkNetLike()
+    model = _model(name)
     return model, init_params(model.specs(), jax.random.PRNGKey(seed))

@@ -20,6 +20,8 @@ import sys
 import time
 import traceback
 
+from repro import enable_compile_cache
+
 from . import (table1, compression, fig1_expectation, fig10_11, fig12,
                fig13, table2_power, darknet_full, faults, kernel_backend,
                ordered_collectives, ordering_throughput, roofline,
@@ -52,6 +54,7 @@ BENCH_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_noc.json")
 
 
 def main() -> None:
+    enable_compile_cache()
     argv = sys.argv[1:]
     transforms = None
     if "--transforms" in argv:

@@ -13,6 +13,7 @@ import argparse
 
 import jax
 
+from repro import enable_compile_cache
 from repro.data import glyph_batch
 from repro.models import LeNet, init_params
 from repro.noc import (AFFINITIES, PAPER_NOCS, PLACEMENTS, SweepGrid,
@@ -42,6 +43,7 @@ ap.add_argument("--o3", action="store_true",
 ap.add_argument("--train-steps", type=int, default=60)
 ap.add_argument("--max-packets", type=int, default=30)
 args = ap.parse_args()
+enable_compile_cache()
 
 model = LeNet()
 params = init_params(model.specs(), jax.random.PRNGKey(0))

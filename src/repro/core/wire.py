@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import ClassVar, Optional
 
 import jax
 import numpy as np
@@ -63,6 +63,9 @@ class WireTransform:
     name: str = "O0"
     window: Optional[int] = None
     tiebreak: str = "stable"   # "pattern" clusters equal-count values
+    # The packetizer runs this transform on the host CPU, not the default
+    # device (see MinHammingTransform).
+    host_only: ClassVar[bool] = False
 
     def overhead_bits_per_value(self, window: int, paired: bool = True) -> int:
         """Recovery bits the receiver needs per transmitted value.
@@ -192,6 +195,10 @@ class MinHammingTransform(WireTransform):
     name: str = "O3"
     beam: int = ordering.DEFAULT_BEAM
     starts: int = ordering.DEFAULT_STARTS
+    # On a TPU v5e the chain scan, batched over many packets, returns orders
+    # that are not permutations (values dropped and repeated), so O3 and
+    # O3a are ordered on the host.
+    host_only: ClassVar[bool] = True
 
     def overhead_bits_per_value(self, window: int, paired: bool = True) -> int:
         return ordering.index_overhead_bits(window)

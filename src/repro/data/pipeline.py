@@ -71,8 +71,18 @@ GLYPHS = np.stack([
 def glyph_batch(key: jax.Array, batch: int, hw: int = 32, channels: int = 1):
     """Procedural digit-like images: upsampled glyph + jitter + noise.
 
-    Returns (images (B, hw, hw, channels) float32 in [0, 1], labels (B,)).
+    Returns (images (B, hw, hw, channels) float32 in [0, 1], labels (B,))
+    on the default device. They are made on the host CPU, so one key gives
+    the same bits on every backend: a TPU's normal sampler differs from the
+    CPU's in the last bits, which moves the fixed-8 traffic built on them.
     """
+    cpu = jax.devices("cpu")[0]
+    with jax.default_device(cpu):
+        img, labels = _glyphs(jax.device_put(key, cpu), batch, hw, channels)
+    return jnp.asarray(np.asarray(img)), jnp.asarray(np.asarray(labels))
+
+
+def _glyphs(key: jax.Array, batch: int, hw: int, channels: int):
     k1, k2, k3, k4 = jax.random.split(key, 4)
     labels = jax.random.randint(k1, (batch,), 0, 10)
     glyphs = jnp.asarray(GLYPHS)[labels]                     # (B, 5, 3)

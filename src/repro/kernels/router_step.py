@@ -9,21 +9,23 @@ live in VMEM at DarkNet scale), and the drain bookkeeping stay in
 ``noc.sim``; the kernel sees one cycle's state plus the M pre-gathered
 injection rows and returns the next state.
 
-Backend contract (``kernels/ops.py``): ``interpret=True`` on CPU so the
-parity suite pins the kernel cycle-for-cycle against the fused step and
-the frozen ``noc._reference`` step everywhere; on TPU the same body
-compiles through Mosaic with the whole state resident in VMEM (an 8x8
-mesh's fused FIFO tensor is ~350 KB, a 16x16's ~5.6 MB). The arithmetic
-is copied from ``noc.sim._make_step`` op for op - same gathers, same
-select chains, same scatter - so the two backends are bit-identical by
-construction, and the parity tests keep them that way. The BT recorder
-uses the SWAR popcount form (``kernels/popcount.py``) instead of
-``lax.population_count``, which Mosaic does not lower; the two are
-pinned equal by the kernel parity suite.
+Backend contract: this kernel is an interpret-mode parity path. With
+``interpret=True`` on CPU the parity suite pins it cycle-for-cycle
+against the fused step and the frozen ``noc._reference`` step. It is not
+on any chip path: the TPU's Mosaic compiler rejects its gathers ("Only
+2D gather is supported") at every mesh size, so ``make_router_step``
+refuses ``interpret=False`` with :data:`TPU_UNSUPPORTED`, and
+``noc.sim``'s ``backend="auto"`` resolves to the fused jnp step on every
+platform. The arithmetic is copied from ``noc.sim._make_step`` op for op
+- same gathers, same select chains, same scatter - so the two backends
+are bit-identical by construction, and the parity tests keep them that
+way. The BT recorder uses the SWAR popcount form
+(``kernels/popcount.py``) instead of ``lax.population_count``; the two
+are pinned equal by the kernel parity suite.
 
 The conservation ledger (``check_conservation``) is a debug path and is
-not carried here - ``noc.sim`` routes tracked drains through the fused
-step regardless of the requested backend.
+not carried here - tracked drains run the fused step, and ``noc.sim``
+refuses an explicit ``backend="pallas"`` with the ledger armed.
 """
 from __future__ import annotations
 
@@ -36,7 +38,12 @@ from jax.experimental import pallas as pl
 from repro.noc.topology import (NocConfig, NUM_PORTS, PORT_E, PORT_LOCAL,
                                 PORT_N, PORT_S, PORT_W)
 
-__all__ = ["router_step_pallas", "make_router_step"]
+__all__ = ["router_step_pallas", "make_router_step", "TPU_UNSUPPORTED"]
+
+TPU_UNSUPPORTED = (
+    "the Pallas router kernel does not compile for TPU: Mosaic rejects its "
+    "gathers (only 2D gathers are supported). Use backend='auto' or "
+    "'fused' there; backend='pallas' is the CPU interpret-mode parity path.")
 
 # Sideband layout - must match noc.sim (imported there and checked by the
 # parity suite; duplicated as literals here to keep the kernel module free
@@ -274,7 +281,11 @@ def make_router_step(mesh_key, count_headers: bool, lf: int, m: int,
     pre-gathered injection rows ``iw`` (M, LF), the ``active`` int32 mask
     (M,), the per-stream injection nodes (M,) and the scalar flit total
     (1,), and returns the 13 next-cycle leaves in the same layout.
+    ``interpret=False`` (a Mosaic compile for TPU) raises ValueError with
+    :data:`TPU_UNSUPPORTED` instead of failing deep inside the compiler.
     """
+    if not interpret:
+        raise ValueError(TPU_UNSUPPORTED)
     rows, cols, num_vcs, vc_depth, lanes = mesh_key
     nr = rows * cols
     p, v, d, l = NUM_PORTS, num_vcs, vc_depth, lanes

@@ -9,10 +9,12 @@ end-to-end; the fixed-8 path quantizes at the memory controller
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .spec import ParamSpec
 from repro.noc.traffic import LayerTraffic, conv_layer_traffic, linear_layer_traffic
@@ -28,6 +30,26 @@ def _conv(x, w, b, stride=1):
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
     y = y + b
     return y[0] if x.ndim == 3 else y
+
+
+def _on_host(extract):
+    """Run a ``layer_traffic`` extraction in f32 on the host CPU and return
+    its streams as uncommitted arrays on the default device.
+
+    The streams are the data every backend packetizes and drains, so they
+    must be the same bits on every platform. A TPU computes f32 convs and
+    matmuls in bf16 passes at default precision, and even at the highest
+    precision it sums in another order than the CPU; one activation that
+    lands on the other side of a fixed-8 rounding step then moves BT.
+    """
+    @functools.wraps(extract)
+    def wrapped(self, params, x):
+        layers = extract(self, *jax.device_put((params, x),
+                                               jax.devices("cpu")[0]))
+        return [LayerTraffic(jnp.asarray(np.asarray(t.inputs)),
+                             jnp.asarray(np.asarray(t.weights)))
+                for t in layers]
+    return wrapped
 
 
 def _pool(x, k=2):
@@ -92,6 +114,7 @@ class LeNet:
         return -jnp.mean(jnp.take_along_axis(
             jax.nn.log_softmax(logits), y[:, None], axis=1))
 
+    @_on_host
     def layer_traffic(self, params, x: jax.Array) -> List[LayerTraffic]:
         """The operand streams one inference injects into the NoC."""
         a = self.activations(params, x)
@@ -172,6 +195,7 @@ class DarkNetLike:
         return -jnp.mean(jnp.take_along_axis(
             jax.nn.log_softmax(logits), y[:, None], axis=1))
 
+    @_on_host
     def layer_traffic(self, params, x: jax.Array) -> List[LayerTraffic]:
         a = self.activations(params, x)
         out = []

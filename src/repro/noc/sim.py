@@ -751,30 +751,30 @@ BACKENDS = ("auto", "fused", "pallas")
 def _resolve_backend(backend: str, track: bool) -> str:
     """Resolve the ``backend=`` knob to a concrete step implementation.
 
-    ``auto`` follows the kernels/ops.py selector contract: the Pallas
-    router-step kernel compiles through Mosaic on TPU and would only
-    *interpret* on CPU, so auto picks ``pallas`` exactly when a TPU backs
-    the default device and the proven fused step otherwise. The
-    conservation ledger is a debug path the kernel does not carry, so an
-    ``auto`` drain with the ledger armed resolves to the fused step (both
-    are pinned bit-identical, making the substitution unobservable) - but
-    an *explicit* ``backend="pallas"`` with ``check_conservation=True``
-    is a contradiction and raises instead of being silently overridden.
+    ``auto`` resolves to the fused jnp step on every platform, TPU
+    included: the TPU's Mosaic compiler rejects the router kernel's
+    multi-dimensional gathers ("Only 2D gather is supported"), so the
+    kernel is not on any chip path. ``pallas`` is the interpret-mode
+    parity path on CPU (pinned bit-identical to the fused step by
+    tests/test_kernel_parity.py) and raises on a TPU. The conservation
+    ledger is a debug path the kernel does not carry, so an explicit
+    ``backend="pallas"`` with ``check_conservation=True`` raises too.
     """
     if backend not in BACKENDS:
         raise ValueError(f"backend must be one of {BACKENDS}, got {backend!r}")
-    if backend == "pallas" and track:
-        raise ValueError(
-            "backend='pallas' cannot honor check_conservation=True: the "
-            "Pallas router kernel does not carry the packet-ledger lane. "
-            "Use backend='auto' (resolves tracked drains to the "
-            "bit-identical fused step) or drop check_conservation.")
-    if backend == "auto":
+    if backend == "pallas":
         from repro.kernels.ops import on_tpu
-        backend = "pallas" if on_tpu() else "fused"
-    if track:
-        backend = "fused"
-    return backend
+        from repro.kernels.router_step import TPU_UNSUPPORTED
+        if on_tpu():
+            raise ValueError(TPU_UNSUPPORTED)
+        if track:
+            raise ValueError(
+                "backend='pallas' cannot honor check_conservation=True: the "
+                "Pallas router kernel does not carry the packet-ledger lane. "
+                "Use backend='auto' (the bit-identical fused step) or drop "
+                "check_conservation.")
+        return "pallas"
+    return "fused"
 
 
 def _make_step_pallas(mesh_key, count_headers: bool, track: bool):
@@ -787,7 +787,6 @@ def _make_step_pallas(mesh_key, count_headers: bool, track: bool):
     VMEM at DarkNet scale, and a one-row-per-stream dynamic slice is
     exactly what XLA already does well.
     """
-    from repro.kernels.ops import on_tpu
     from repro.kernels.router_step import router_step_pallas
 
     if track:
@@ -795,7 +794,6 @@ def _make_step_pallas(mesh_key, count_headers: bool, track: bool):
                          "ledger; tracked drains use the fused step")
     rows, cols, num_vcs, vc_depth, lanes = mesh_key
     lf = lanes + 1
-    interp = not on_tpu()
 
     def step(state: SimState, wire: Wire, mc_nodes: jax.Array):
         m = wire.length.shape[0]
@@ -813,7 +811,7 @@ def _make_step_pallas(mesh_key, count_headers: bool, track: bool):
         (fifo, head, count, rr, link_last, link_bt, link_flits, inj_ptr,
          inj_last, inj_bt, ejected, cycle, drained) = router_step_pallas(
             mesh_key, count_headers, lf, leaves, iw, active,
-            mc_nodes.astype(jnp.int32), total, interpret=interp)
+            mc_nodes.astype(jnp.int32), total, interpret=True)
         return SimState(fifo.reshape(state.fifo.shape), head, count, rr,
                         link_last, link_bt, link_flits, inj_ptr, inj_last,
                         inj_bt, ejected[0], cycle[0], None, drained[0])
@@ -864,8 +862,6 @@ def _sharded_chunk_runner(mesh_key, count_headers: bool, chunk: int,
     construction, and the only cross-device traffic is the host readback
     of the drain bookkeeping between chunks.
     """
-    from jax.experimental.shard_map import shard_map
-
     step = _step_for(mesh_key, count_headers, track, backend)
 
     def run(state: SimState, wire: Wire, mc_nodes: jax.Array):
@@ -876,9 +872,9 @@ def _sharded_chunk_runner(mesh_key, count_headers: bool, chunk: int,
 
     run = jax.vmap(run, in_axes=(0, 0, 0))
     spec_b = jax.sharding.PartitionSpec(dev_mesh.axis_names[0])
-    run = shard_map(run, mesh=dev_mesh,
-                    in_specs=(spec_b, spec_b, spec_b),
-                    out_specs=(spec_b, spec_b), check_rep=False)
+    run = jax.shard_map(run, mesh=dev_mesh,
+                        in_specs=(spec_b, spec_b, spec_b),
+                        out_specs=(spec_b, spec_b), check_vma=False)
     return jax.jit(run, donate_argnums=0)
 
 
@@ -985,10 +981,10 @@ def simulate(cfg: NocConfig, traffic: Traffic, *, count_headers: bool = True,
         The result phase passes ``cfg.pe_nodes``: streams then inject at
         the PEs and eject at the MCs their ``dest`` fields name.
     backend: step implementation - ``"fused"`` (the pure-jnp fused step),
-        ``"pallas"`` (the ``kernels/router_step.py`` kernel: Mosaic on
-        TPU, interpret-mode on CPU), or ``"auto"`` (pallas on TPU, fused
-        otherwise; see :func:`_resolve_backend`). All backends are pinned
-        bit-identical.
+        ``"auto"`` (the fused step on every platform), or ``"pallas"``
+        (the ``kernels/router_step.py`` kernel in interpret mode: a CPU
+        parity path that raises on TPU; see :func:`_resolve_backend`).
+        All backends are pinned bit-identical.
     """
     m = int(traffic.length.shape[0])
     if mc_nodes is None:

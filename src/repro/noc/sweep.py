@@ -123,11 +123,11 @@ class SweepGrid:
     baseline: str = "O0"
     result_phase: bool = False
     result_window: Optional[int] = None
-    # Simulator step implementation ("auto"/"fused"/"pallas"): "auto"
-    # follows the kernels/ops.py selector - the Pallas router kernel on
-    # TPU, the fused jnp step elsewhere. Forwarded to every
-    # ``simulate_batch`` call the sweep makes; all backends are pinned
-    # bit-identical, so this is purely a speed knob.
+    # Simulator step implementation ("auto"/"fused"/"pallas"): "auto" is
+    # the fused jnp step on every platform; "pallas" is the CPU
+    # interpret-mode parity path and raises on TPU (see
+    # ``sim._resolve_backend``). Forwarded to every ``simulate_batch``
+    # call the sweep makes; all backends are pinned bit-identical.
     backend: str = "auto"
     # Autotuned drain scheduling: path to a ``noc.tune`` winners table
     # (JSON, see ``repro.noc.tune``). When set, each mesh looks up its

@@ -144,11 +144,26 @@ def _packet_fn(transform: WireTransform, lanes: int,
     the stack shares, whereas a whole-program jit would recompile per
     (transform, layer shape) combination - measurably slower for the one
     pass per model a sweep performs."""
+    return _placed(transform, _packet_vmap(transform, lanes, compression))
 
+
+def _packet_vmap(transform: WireTransform, lanes: int, compression: str):
     def one_packet(i, w):
         return _packet_words(transform, i, w, lanes, compression)
 
     return jax.vmap(one_packet)
+
+
+def _placed(transform: WireTransform, fn):
+    """``fn`` as is, or run on the host CPU for a ``host_only`` transform
+    (its operands are committed there, so jitted work follows them)."""
+    if not transform.host_only:
+        return fn
+
+    def on_host(*args):
+        return fn(*jax.device_put(args, jax.devices("cpu")[0]))
+
+    return on_host
 
 
 def _payload_words(inp: jax.Array, wgt: jax.Array, transform: WireTransform,
@@ -216,8 +231,8 @@ def _packet_chunk_fn(transform: WireTransform, lanes: int,
     the shared vmap keeps the one-shot and streamed paths on a single
     transform kernel.
     """
-    fn = _packet_fn(transform, lanes, compression)
-    return jax.jit(lambda i, w: fn(i, w).astype(jnp.uint32))
+    fn = _packet_vmap(transform, lanes, compression)
+    return _placed(transform, jax.jit(lambda i, w: fn(i, w).astype(jnp.uint32)))
 
 
 def payload_shapes(
@@ -916,7 +931,7 @@ def _result_packet_fn(transform: WireTransform, lanes: int,
             return transform.apply_single(vals, lanes).words
         return msr.msr_pack(transform.order_single(vals, lanes), lanes).words
 
-    return jax.vmap(one_packet)
+    return _placed(transform, jax.vmap(one_packet))
 
 
 def build_result_traffic(

@@ -2,7 +2,9 @@
 (popcount, bt_count, bitonic_sort, chain-select, and the router step -
 interpret mode on CPU) against the repro.kernels.ref / numpy oracles
 across wire dtypes (fp32, bf16, int8) and odd, padding-exercising shapes.
-Pins the kernel semantics the compiled Mosaic path inherits on TPU."""
+Pins the kernel semantics the compiled Mosaic path inherits on TPU -
+except the router step, which Mosaic rejects (its gathers): it is a CPU
+parity path only, and the TPU drain runs the fused step."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,7 +22,7 @@ from repro.kernels.ref import (bt_boundaries_ref, popcount_ref,
 from repro.models import LeNet, init_params
 from repro.noc import PAPER_NOCS, mesh_by_name
 from repro.noc._reference import simulate_unfused
-from repro.noc.sim import simulate, simulate_batch
+from repro.noc.sim import _resolve_backend, simulate, simulate_batch
 from repro.noc.traffic import build_traffic, stack_traffics
 from repro.quant import quantize_fixed8
 
@@ -179,11 +181,24 @@ def test_router_kernel_matches_frozen_reference(pinned_layers):
         assert np.array_equal(new.link_bt, ref.link_bt), cell
 
 
-def test_router_backend_validation():
+def test_router_backend_validation(monkeypatch):
     cfg = mesh_by_name("2x2_mc1")
     tr = build_traffic([], cfg, by_name("O0"))
     with pytest.raises(ValueError, match="backend"):
         simulate(cfg, tr, backend="mosaic2000")
+    # One step per platform: auto is the fused step everywhere, tracked or
+    # not; pallas is the CPU interpret-mode parity path.
+    for track in (False, True):
+        assert _resolve_backend("auto", track) == "fused"
+        assert _resolve_backend("fused", track) == "fused"
+    assert _resolve_backend("pallas", False) == "pallas"
+    # On a TPU the kernel does not compile: auto stays fused, and an
+    # explicit pallas raises the clear error before any drain starts.
+    import repro.kernels.ops as ops
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert _resolve_backend("auto", False) == "fused"
+    with pytest.raises(ValueError, match="does not compile for TPU"):
+        simulate(cfg, tr, backend="pallas")
 
 
 # ---------------------------------------------------------------------------

@@ -3,9 +3,12 @@ one train step on CPU, asserting output shapes and no NaNs (the FULL
 configs are exercised only via the dry-run)."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import ARCHS, get
+from repro.data import glyph_batch
+from repro.models import DarkNetLike, LeNet
 from repro.models.spec import init_params
 from repro.optim import AdamW, constant
 from repro.train import make_train_step, init_state
@@ -148,3 +151,23 @@ def test_assigned_full_configs_match_table():
     assert (c.d_ff, c.pattern) == (0, ("mlstm", "slstm"))
     c = get("whisper-medium").config
     assert (c.n_layers, c.d_model, c.vocab) == (24, 1024, 51865)
+
+
+@pytest.mark.parametrize("model", [LeNet(), DarkNetLike()],
+                         ids=["lenet", "darknet"])
+def test_layer_traffic_made_on_host(model):
+    """The glyph input and the traffic extraction are computed on the host
+    CPU and handed back as uncommitted default-device arrays, bit-identical
+    to the extraction called directly (a committed CPU array would pull the
+    packetizer off an accelerator)."""
+    params = init_params(model.specs(), jax.random.PRNGKey(0))
+    x, labels = glyph_batch(jax.random.PRNGKey(7), 1, hw=model.input_shape[0],
+                            channels=model.input_shape[-1])
+    assert not x.committed and not labels.committed
+    got = model.layer_traffic(params, x[0])
+    want = type(model).layer_traffic.__wrapped__(model, params, x[0])
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        for a, b in ((g.inputs, w.inputs), (g.weights, w.weights)):
+            assert not a.committed
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

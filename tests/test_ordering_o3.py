@@ -217,3 +217,22 @@ def test_o3_kernel_validation_errors():
     with pytest.raises(ValueError, match="shape"):
         min_hamming_chain((jnp.zeros((2, 4), jnp.uint8),
                            jnp.zeros((2, 5), jnp.uint8)))
+
+
+@pytest.mark.parametrize("name", ["O1", "O3", "O3a"])
+def test_packet_fns_order_o3_on_host(name):
+    """The O3 family is packetized on the host CPU (its batched chain scan
+    is wrong on a TPU); the one-shot and streamed packet functions agree
+    and hand back host arrays for it."""
+    from repro.noc import traffic
+
+    tr = by_name(name)
+    assert tr.host_only == name.startswith("O3")
+    i = jnp.asarray(rng.integers(-128, 128, (6, 24)), jnp.int8)
+    w = jnp.asarray(rng.integers(-128, 128, (6, 24)), jnp.int8)
+    shot = traffic._packet_fn(tr, 16)(i, w)
+    chunk = traffic._packet_chunk_fn(tr, 16)(i, w)
+    np.testing.assert_array_equal(np.asarray(shot).astype(np.uint32),
+                                  np.asarray(chunk))
+    if tr.host_only:
+        assert chunk.devices() == {jax.devices("cpu")[0]} and chunk.committed
