@@ -56,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.bits import popcount_hw
+from .spans import count, span
 from .topology import (NocConfig, NUM_PORTS, OPPOSITE, PORT_E, PORT_LOCAL,
                        PORT_N, PORT_S, PORT_W)
 
@@ -1081,91 +1082,97 @@ def simulate_batch(cfg: NocConfig, traffic: Traffic, *,
         still retire their bookkeeping). Pure scheduling: results are
         bit-identical across ratios.
     """
-    if not 0.0 <= compact_ratio <= 1.0:
-        raise ValueError(f"compact_ratio must be in [0, 1], "
-                         f"got {compact_ratio!r}")
-    if traffic.length.ndim != 2:
-        raise ValueError("simulate_batch wants a leading variants axis; "
-                         "use simulate() for a single Traffic")
-    b, m = traffic.length.shape
-    if mc_nodes is None:
-        default_nodes = np.asarray(_mc_array(cfg, traffic, m, batched=True))
-        mc = np.broadcast_to(default_nodes, (b, m)).copy()
-    else:
-        mc = np.ascontiguousarray(np.asarray(mc_nodes, np.int32))
-        if mc.shape != (b, m):
-            raise ValueError(f"mc_nodes must be ({b}, {m}), got {mc.shape}")
-        if mc.size and (mc.min() < 0 or mc.max() >= cfg.num_routers):
-            raise ValueError("mc_nodes out of range for a "
-                             f"{cfg.num_routers}-router config")
-    _validate_fields(cfg, traffic)
-    npkt = _npkt(traffic) if check_conservation else 0
-    track = npkt > 0
-    host_cons = ((np.asarray(traffic.length), np.asarray(traffic.meta),
-                  np.asarray(traffic.pkt)) if track else None)
-    totals = np.asarray(traffic.length).sum(axis=1).astype(np.int64)
-    wire = fuse_traffic(traffic, track)
-    bk = _resolve_backend(backend, track)
+    with span("noc.drain.setup"):
+        if not 0.0 <= compact_ratio <= 1.0:
+            raise ValueError(f"compact_ratio must be in [0, 1], "
+                             f"got {compact_ratio!r}")
+        if traffic.length.ndim != 2:
+            raise ValueError("simulate_batch wants a leading variants axis; "
+                             "use simulate() for a single Traffic")
+        b, m = traffic.length.shape
+        if mc_nodes is None:
+            default_nodes = np.asarray(
+                _mc_array(cfg, traffic, m, batched=True))
+            mc = np.broadcast_to(default_nodes, (b, m)).copy()
+        else:
+            mc = np.ascontiguousarray(np.asarray(mc_nodes, np.int32))
+            if mc.shape != (b, m):
+                raise ValueError(
+                    f"mc_nodes must be ({b}, {m}), got {mc.shape}")
+            if mc.size and (mc.min() < 0 or mc.max() >= cfg.num_routers):
+                raise ValueError("mc_nodes out of range for a "
+                                 f"{cfg.num_routers}-router config")
+        _validate_fields(cfg, traffic)
+        npkt = _npkt(traffic) if check_conservation else 0
+        track = npkt > 0
+        host_cons = ((np.asarray(traffic.length), np.asarray(traffic.meta),
+                      np.asarray(traffic.pkt)) if track else None)
+        totals = np.asarray(traffic.length).sum(axis=1).astype(np.int64)
+        wire = fuse_traffic(traffic, track)
+        bk = _resolve_backend(backend, track)
 
-    if isinstance(devices, jax.sharding.Mesh):
-        if len(devices.axis_names) != 1:
-            raise ValueError("simulate_batch wants a 1-D device mesh, got "
-                             f"axes {devices.axis_names}")
-        dev_mesh, ndev = devices, int(devices.devices.size)
-    elif devices is not None:
-        devs = list(devices)
-        ndev = len(devs)
-        dev_mesh = (jax.sharding.Mesh(np.asarray(devs), ("variants",))
-                    if ndev > 1 else None)
-    else:
-        dev_mesh, ndev = None, 0
-    sharded = ndev > 1
-    if sharded:
-        # Lazy import: repro.dist pulls in repro.models, which imports this
-        # package back for its layer_traffic helpers.
-        from repro.dist.sharding import batch_shardings, compact_batch
-        bp = -(-b // ndev) * ndev
-        if bp != b:
-            zpad = lambda x: jnp.concatenate(   # noqa: E731
-                [x, jnp.zeros((bp - b,) + x.shape[1:], x.dtype)])
-            wire = Wire(zpad(wire.wire), zpad(wire.length))
-            mc = np.concatenate([mc, np.zeros((bp - b, m), np.int32)])
-            totals = np.concatenate([totals, np.zeros(bp - b, np.int64)])
-        axis = dev_mesh.axis_names[0]
-        place = lambda tree: jax.device_put(  # noqa: E731
-            tree, batch_shardings(dev_mesh, tree, axis))
-        compact = lambda tree, idx: compact_batch(  # noqa: E731
-            dev_mesh, tree, idx, axis)
-        run_chunk = _sharded_chunk_runner(_mesh_key(cfg), count_headers,
-                                          chunk, dev_mesh, track, bk)
-        min_rows = ndev
-    else:
-        bp = b
-        place = lambda tree: tree  # noqa: E731
-        compact = lambda tree, idx: jax.tree.map(  # noqa: E731
-            lambda x: x[idx], tree)
-        run_chunk = _chunk_runner(_mesh_key(cfg), count_headers, chunk, True,
-                                  track, bk)
-        min_rows = 1
+        if isinstance(devices, jax.sharding.Mesh):
+            if len(devices.axis_names) != 1:
+                raise ValueError("simulate_batch wants a 1-D device mesh, got "
+                                 f"axes {devices.axis_names}")
+            dev_mesh, ndev = devices, int(devices.devices.size)
+        elif devices is not None:
+            devs = list(devices)
+            ndev = len(devs)
+            dev_mesh = (jax.sharding.Mesh(np.asarray(devs), ("variants",))
+                        if ndev > 1 else None)
+        else:
+            dev_mesh, ndev = None, 0
+        sharded = ndev > 1
+        if sharded:
+            # Lazy import: repro.dist pulls in repro.models, which imports
+            # this package back for its layer_traffic helpers.
+            from repro.dist.sharding import batch_shardings, compact_batch
+            bp = -(-b // ndev) * ndev
+            if bp != b:
+                zpad = lambda x: jnp.concatenate(   # noqa: E731
+                    [x, jnp.zeros((bp - b,) + x.shape[1:], x.dtype)])
+                wire = Wire(zpad(wire.wire), zpad(wire.length))
+                mc = np.concatenate([mc, np.zeros((bp - b, m), np.int32)])
+                totals = np.concatenate([totals, np.zeros(bp - b, np.int64)])
+            axis = dev_mesh.axis_names[0]
+            place = lambda tree: jax.device_put(  # noqa: E731
+                tree, batch_shardings(dev_mesh, tree, axis))
+            compact = lambda tree, idx: compact_batch(  # noqa: E731
+                dev_mesh, tree, idx, axis)
+            run_chunk = _sharded_chunk_runner(_mesh_key(cfg), count_headers,
+                                              chunk, dev_mesh, track, bk)
+            min_rows = ndev
+        else:
+            bp = b
+            place = lambda tree: tree  # noqa: E731
+            compact = lambda tree, idx: jax.tree.map(  # noqa: E731
+                lambda x: x[idx], tree)
+            run_chunk = _chunk_runner(_mesh_key(cfg), count_headers, chunk,
+                                      True, track, bk)
+            min_rows = 1
 
-    # Broadcast the zeroed base state instead of stacking B host copies;
-    # the first chunk call takes ownership of the buffer via donation.
-    base = make_state(cfg, m, npkt=npkt)
-    state = place(jax.tree.map(
-        lambda x: jnp.broadcast_to(x, (bp,) + x.shape), base))
-    wire = place(wire)
-    mc_dev = place(jnp.asarray(mc, jnp.int32))
+        # Broadcast the zeroed base state instead of stacking B host copies;
+        # the first chunk call takes ownership of the buffer via donation.
+        base = make_state(cfg, m, npkt=npkt)
+        state = place(jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (bp,) + x.shape), base))
+        wire = place(wire)
+        mc_dev = place(jnp.asarray(mc, jnp.int32))
 
     harvested = {}      # lane id -> host bookkeeping leaves
 
     def harvest(st, pairs):
-        leaves = [np.asarray(st.link_bt), np.asarray(st.link_flits),
-                  np.asarray(st.inj_bt), np.asarray(st.ejected),
-                  np.asarray(st.cycle), np.asarray(st.drained_at)]
-        ep = np.asarray(st.eject_pkt) if track else None
-        for lane, row in pairs:
-            harvested[lane] = tuple(a[row] for a in leaves) + (
-                (ep[row],) if track else (None,))
+        leaves = (st.link_bt, st.link_flits, st.inj_bt, st.ejected,
+                  st.cycle, st.drained_at) + ((st.eject_pkt,) if track else ())
+        with span("noc.drain.wait"):
+            jax.block_until_ready(leaves)
+        with span("noc.drain.retire"):
+            host = [np.asarray(x) for x in leaves]
+            ep = host[6] if track else None
+            for lane, row in pairs:
+                harvested[lane] = tuple(a[row] for a in host[:6]) + (
+                    (ep[row],) if track else (None,))
 
     if totals.sum() == 0:   # empty traffic: nothing to drain (and T may be 0)
         harvest(state, [(lane, lane) for lane in range(bp)])
@@ -1173,13 +1180,16 @@ def simulate_batch(cfg: NocConfig, traffic: Traffic, *,
         live = list(range(bp))          # lanes still draining
         prim = {lane: lane for lane in live}    # lane -> device row
         state, ej = run_chunk(state, wire, mc_dev)
+        count("drain.stepped_cycles", chunk)
         nch = 1
         while True:
             # Pipelined driver: dispatch chunk k+1, then read chunk k's
             # bookkeeping - the readback no longer leaves the device idle.
             state2, ej2 = run_chunk(state, wire, mc_dev)
+            count("drain.stepped_cycles", chunk)
             nch += 1
-            e = np.asarray(ej)          # ejected after chunk nch-1
+            with span("noc.drain.wait"):
+                e = np.asarray(ej)      # ejected after chunk nch-1
             done = [lane for lane in live if e[prim[lane]] >= totals[lane]]
             if len(done) == len(live):
                 harvest(state2, [(lane, prim[lane]) for lane in live])
@@ -1205,33 +1215,36 @@ def simulate_batch(cfg: NocConfig, traffic: Traffic, *,
                 # Retire drained lanes: their recorders froze at the exact
                 # drain_cycle, so chunk k+1's rows hold their final state.
                 harvest(state2, [(lane, prim[lane]) for lane in done])
-                live = [lane for lane in live if lane not in set(done)]
-                cur = int(ej2.shape[0])
-                target = max(_next_pow2(len(live)), min_rows)
-                if target % min_rows:
-                    target = -(-target // min_rows) * min_rows
-                if len(live) <= int(cur * compact_ratio) and target < cur:
-                    keep = [prim[lane] for lane in live]
-                    rows = keep + [keep[0]] * (target - len(keep))
-                    idx = jnp.asarray(rows, jnp.int32)
-                    state2 = compact(state2, idx)
-                    wire = compact(wire, idx)
-                    mc_dev = compact(mc_dev, idx)
-                    ej2 = compact(ej2, idx)
-                    prim = {lane: i for i, lane in enumerate(live)}
+                with span("noc.drain.retire"):
+                    live = [lane for lane in live if lane not in set(done)]
+                    cur = int(ej2.shape[0])
+                    target = max(_next_pow2(len(live)), min_rows)
+                    if target % min_rows:
+                        target = -(-target // min_rows) * min_rows
+                    if len(live) <= int(cur * compact_ratio) and target < cur:
+                        keep = [prim[lane] for lane in live]
+                        rows = keep + [keep[0]] * (target - len(keep))
+                        idx = jnp.asarray(rows, jnp.int32)
+                        state2 = compact(state2, idx)
+                        wire = compact(wire, idx)
+                        mc_dev = compact(mc_dev, idx)
+                        ej2 = compact(ej2, idx)
+                        prim = {lane: i for i, lane in enumerate(live)}
             state, ej = state2, ej2
 
     out = []
-    for i in range(b):
-        (link_bt, link_flits, inj_bt, ejected, cycle, drained_at,
-         eject_pkt) = harvested[i]
-        if check_conservation and track:
-            length, meta, pkt = host_cons
-            err = _conservation_error(length[i], meta[i], pkt[i],
-                                      eject_pkt, npkt)
-            if err:
-                raise RuntimeError(
-                    f"packet conservation violated (variant {i}): {err}")
-        out.append(_result(cfg, (link_bt, link_flits, inj_bt, ejected,
-                                 cycle, drained_at), int(totals[i])))
+    with span("noc.drain.retire"):
+        for i in range(b):
+            (link_bt, link_flits, inj_bt, ejected, cycle, drained_at,
+             eject_pkt) = harvested[i]
+            if check_conservation and track:
+                length, meta, pkt = host_cons
+                err = _conservation_error(length[i], meta[i], pkt[i],
+                                          eject_pkt, npkt)
+                if err:
+                    raise RuntimeError(
+                        f"packet conservation violated (variant {i}): {err}")
+            out.append(_result(cfg, (link_bt, link_flits, inj_bt, ejected,
+                                     cycle, drained_at), int(totals[i])))
+    count("drain.cycles", max((r.drain_cycle for r in out), default=0))
     return out

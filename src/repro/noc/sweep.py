@@ -25,7 +25,6 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-import time
 from typing import Callable, Dict, List, Optional, Sequence, Union
 
 import jax
@@ -44,6 +43,7 @@ from .traffic import (DEFAULT_RESULT_WINDOW, LayerTraffic, assemble_traffic,
                       ordered_payloads, pad_traffic_length, payload_shapes,
                       result_values, stream_lengths)
 from .sim import SimResult, Traffic, simulate_batch
+from .spans import SpanLog, recording, span
 
 __all__ = ["SweepGrid", "SweepReport", "run_sweep", "run_serving",
            "recovery_overhead_bits", "drain_estimate"]
@@ -277,7 +277,6 @@ def cached_ordered_payloads(cache: Dict[tuple, list], model: str,
                             layers: Sequence[LayerTraffic], lanes: int,
                             variants, axes,
                             max_packets_per_layer: Optional[int],
-                            timings: Optional[Dict[str, float]] = None,
                             compression: str = "none") -> list:
     """Ordered payloads for ``variants``, cached per (model, lanes,
     transform, precision, compression).
@@ -291,23 +290,19 @@ def cached_ordered_payloads(cache: Dict[tuple, list], model: str,
     bit-identical to an uncached :func:`repro.noc.traffic.ordered_payloads`
     call over the full variant list.
 
-    ``timings`` (transform name -> seconds, accumulated in place) charges
-    each cache *miss* to its ordering - the per-transform packetization
-    breakdown the bench trajectory records, so an O3 chain regression is
-    attributable against the cheap O0-O2 permutes.
+    Each cache *miss* is a ``noc.packetize.order`` span with its
+    ``transform``, the per-transform packetization breakdown, so an O3
+    chain regression is attributable against the cheap O0-O2 permutes.
     """
     stacks = []
     for (tr, q), (prec, _, _) in zip(variants, axes):
         key = (model, lanes, tr, prec, compression)
         if key not in cache:
-            t0 = time.perf_counter()
-            cache[key] = ordered_payloads(
-                layers, lanes, [(tr, q)],
-                max_packets_per_layer=max_packets_per_layer,
-                compression=compression)
-            if timings is not None:
-                timings[tr.name] = (timings.get(tr.name, 0.0)
-                                    + time.perf_counter() - t0)
+            with span("noc.packetize.order", transform=tr.name):
+                cache[key] = ordered_payloads(
+                    layers, lanes, [(tr, q)],
+                    max_packets_per_layer=max_packets_per_layer,
+                    compression=compression)
         stacks.append(cache[key])
     return [np.concatenate([s[li] for s in stacks])
             for li in range(len(stacks[0]))]
@@ -420,16 +415,69 @@ def run_sweep(grid: SweepGrid, layers_for_model: LayersFn, *,
         devices on multi-device hosts and falls back to the single-device
         vmapped drain otherwise (per-variant results are bit-identical
         either way).
+
+    The sweep records its named spans (``repro.noc.spans``): ``stats``
+    gives each span's seconds, self seconds and count under ``spans``, the
+    drain counters under ``counters``, and its ``*_s`` timings read those
+    spans.
     """
+    devs = _resolve_devices(devices)
+    with recording() as log:
+        with span("noc.sweep"):
+            rows, classes, stepped_cycles, result_cycles = _sweep_cells(
+                grid, layers_for_model, log, check_conservation, devs)
+    pack_s = log.seconds("noc.packetize")
+    sim_s = log.seconds("noc.drain")
+    res_pack_s = log.seconds("noc.result.packetize")
+    res_s = log.seconds("noc.result.drain")
+    wall = pack_s + sim_s + res_pack_s + res_s
+    stats = {
+        "cells": len(rows),
+        "shape_classes": classes,
+        "packetize_s": round(pack_s, 4),
+        # Ordering seconds per transform (the ``transform`` of each
+        # noc.packetize.order span; quantization included, assembly and
+        # simulation excluded) - lets an O3 chain regression show up
+        # against the cheap O0-O2 permutes.
+        "packetize_by_transform": {
+            k: round(v, 4) for k, v in sorted(
+                log.by_arg("noc.packetize.order", "transform").items())},
+        "simulate_s": round(sim_s, 4),
+        "wall_s": round(wall, 4),
+        "stepped_cycles": stepped_cycles,
+        "cycles_per_sec": round(stepped_cycles / sim_s, 1) if sim_s else None,
+        "streamed": grid.max_packets_per_layer is None,
+        "devices": len(devs) if devs else 1,
+        "result_phase": grid.result_phase,
+    }
+    if grid.result_phase:
+        stats["result_packetize_s"] = round(res_pack_s, 4)
+        stats["result_simulate_s"] = round(res_s, 4)
+        stats["result_cycles"] = result_cycles
+        stats["result_cycles_per_sec"] = (
+            round(result_cycles / res_s, 1) if res_s else None)
+    stats["spans"] = log.totals()
+    stats["counters"] = dict(sorted(log.counters.items()))
+    report = SweepReport(rows=rows, stats=stats)
+    if out_path:
+        os.makedirs(os.path.dirname(os.path.abspath(out_path)), exist_ok=True)
+        with open(out_path, "w") as f:
+            json.dump({"grid": _grid_json(grid), "rows": rows,
+                       "stats": stats}, f, indent=1)
+    return report
+
+
+def _sweep_cells(grid: SweepGrid, layers_for_model: LayersFn, log: SpanLog,
+                 check_conservation: bool, devs):
+    """The cells of :func:`run_sweep`: its rows, one entry per shape
+    class, and the request and result cycles stepped across all
+    variants."""
     axes = grid.variant_axes()
     variants = [(by_name(tr, tiebreak=tb), _QUANTIZERS[prec])
                 for prec, tb, tr in axes]
-    devs = _resolve_devices(devices)
     streamed = grid.max_packets_per_layer is None
     rows: List[dict] = []
     classes = []
-    pack_s = sim_s = res_pack_s = res_s = 0.0
-    pack_by_tr: Dict[str, float] = {}   # ordering seconds per transform
     stepped_cycles = 0          # request cycle-steps across all variants
     result_cycles = 0           # result-phase cycle-steps
     # Result values depend only on (model, variants) - computed once and
@@ -486,103 +534,106 @@ def run_sweep(grid: SweepGrid, layers_for_model: LayersFn, *,
                 layer_cache[model] = layers_for_model(model)
             layers = layer_cache[model]
 
-            t0 = time.perf_counter()
-            pkey = (model, base_cfg.lanes, comp)
-            if pkey not in shape_cache:
+            with span("noc.packetize"):
+                pkey = (model, base_cfg.lanes, comp)
+                if pkey not in shape_cache:
+                    if streamed:
+                        # One single-packet geometry probe per model; the
+                        # payloads themselves never materialize whole.
+                        shape_cache[pkey] = payload_shapes(
+                            layers, base_cfg.lanes, variants,
+                            max_packets_per_layer=grid.max_packets_per_layer,
+                            compression=comp)
+                    else:
+                        # The one-shot path reads the geometry off the
+                        # payload arrays it needs anyway - probing all
+                        # variants again would double the transform work.
+                        payload_cache[pkey] = cached_ordered_payloads(
+                            ordered_cache, model, layers, base_cfg.lanes,
+                            variants, axes,
+                            max_packets_per_layer=grid.max_packets_per_layer,
+                            compression=comp)
+                        shape_cache[pkey] = [(w.shape[1], w.shape[2])
+                                             for w in payload_cache[pkey]]
+                group = size_groups[(base_cfg.rows, base_cfg.cols,
+                                     base_cfg.num_vcs, base_cfg.vc_depth,
+                                     base_cfg.lanes)]
+                shapes = shape_cache[pkey]
+                npackets = sum(n for n, _ in shapes)
+                mc_pad = max(c.num_mcs for c in group)
+
+                # Every (MC placement x packet->MC affinity) combination of
+                # this (mesh, model) drains in ONE batched call: combos share
+                # the traffic shapes (padded below) and differ only in their
+                # per-lane mc_nodes / per-MC stream split, so the drain
+                # scheduler can retire fast lanes while congested ones keep
+                # stepping.
+                placed = [(pl, aff, _place(base_cfg, pl))
+                          for pl in grid.placements for aff in grid.affinity]
+                tables = [affinity_mc_table(cfg) if aff == "nearest" else None
+                          for _, aff, cfg in placed]
+                lens = [stream_lengths(shapes, cfg.num_mcs, tbl)
+                        for (_, _, cfg), tbl in zip(placed, tables)]
+                # Affinity skews the per-MC split, so the common stream length
+                # covers every placement x affinity combo of every member of
+                # the size group - same-size meshes keep sharing one compiled
+                # drain under the new axis. The base config's combos are
+                # already in `lens`; only other group members recompute.
+                t_pad = max(
+                    [int(ln.max()) for ln in lens]
+                    + [int(stream_lengths(
+                        shapes, gcfg.num_mcs,
+                        affinity_mc_table(gcfg) if aff == "nearest" else None
+                       ).max())
+                       for c in group if c is not base_cfg
+                       for pl in grid.placements
+                       for aff in grid.affinity
+                       for gcfg in (_place(c, pl),)])
                 if streamed:
-                    # One single-packet geometry probe per model; the
-                    # payloads themselves never materialize whole.
-                    shape_cache[pkey] = payload_shapes(
-                        layers, base_cfg.lanes, variants,
-                        max_packets_per_layer=grid.max_packets_per_layer,
+                    # ONE ordering pass for every placement x affinity combo:
+                    # the transform output is mesh-independent, so each chunk
+                    # is ordered once and scattered into all combo layouts.
+                    combo_traffics = build_traffic_streamed_multi(
+                        layers, [cfg for _, _, cfg in placed], variants,
+                        chunk_packets=grid.stream_chunk_packets,
+                        num_streams=mc_pad, shapes=shapes, mc_tables=tables,
                         compression=comp)
                 else:
-                    # The one-shot path reads the geometry off the
-                    # payload arrays it needs anyway - probing all
-                    # variants again would double the transform work.
-                    payload_cache[pkey] = cached_ordered_payloads(
-                        ordered_cache, model, layers, base_cfg.lanes,
-                        variants, axes,
-                        max_packets_per_layer=grid.max_packets_per_layer,
-                        timings=pack_by_tr, compression=comp)
-                    shape_cache[pkey] = [(w.shape[1], w.shape[2])
-                                         for w in payload_cache[pkey]]
-            group = size_groups[(base_cfg.rows, base_cfg.cols,
-                                 base_cfg.num_vcs, base_cfg.vc_depth,
-                                 base_cfg.lanes)]
-            shapes = shape_cache[pkey]
-            npackets = sum(n for n, _ in shapes)
-            mc_pad = max(c.num_mcs for c in group)
-
-            # Every (MC placement x packet->MC affinity) combination of
-            # this (mesh, model) drains in ONE batched call: combos share
-            # the traffic shapes (padded below) and differ only in their
-            # per-lane mc_nodes / per-MC stream split, so the drain
-            # scheduler can retire fast lanes while congested ones keep
-            # stepping.
-            placed = [(pl, aff, _place(base_cfg, pl))
-                      for pl in grid.placements for aff in grid.affinity]
-            tables = [affinity_mc_table(cfg) if aff == "nearest" else None
-                      for _, aff, cfg in placed]
-            lens = [stream_lengths(shapes, cfg.num_mcs, tbl)
-                    for (_, _, cfg), tbl in zip(placed, tables)]
-            # Affinity skews the per-MC split, so the common stream length
-            # covers every placement x affinity combo of every member of
-            # the size group - same-size meshes keep sharing one compiled
-            # drain under the new axis. The base config's combos are
-            # already in `lens`; only other group members recompute.
-            t_pad = max(
-                [int(ln.max()) for ln in lens]
-                + [int(stream_lengths(
-                    shapes, gcfg.num_mcs,
-                    affinity_mc_table(gcfg) if aff == "nearest" else None
-                   ).max())
-                   for c in group if c is not base_cfg
-                   for pl in grid.placements
-                   for aff in grid.affinity
-                   for gcfg in (_place(c, pl),)])
-            if streamed:
-                # ONE ordering pass for every placement x affinity combo:
-                # the transform output is mesh-independent, so each chunk
-                # is ordered once and scattered into all combo layouts.
-                combo_traffics = build_traffic_streamed_multi(
-                    layers, [cfg for _, _, cfg in placed], variants,
-                    chunk_packets=grid.stream_chunk_packets,
-                    num_streams=mc_pad, shapes=shapes, mc_tables=tables,
-                    compression=comp)
-            else:
-                combo_traffics = [
-                    assemble_traffic(payload_cache[pkey], cfg,
-                                     num_streams=mc_pad, num_variants=nv,
-                                     mc_table=tbl)
-                    for (_, _, cfg), tbl in zip(placed, tables)]
-            parts = [pad_traffic_length(t, t_pad) for t in combo_traffics]
-            del combo_traffics
-            traffic = _concat_lanes(parts)
-            del parts
-            mc_rows = np.stack(
-                [np.asarray(tuple(cfg.mc_nodes) + (0,) * (mc_pad - cfg.num_mcs),
-                            np.int32)
-                 for _, _, cfg in placed for _ in range(nv)])
-            # Drain-aware lane order: deal estimate-sorted lanes across the
-            # device shards so no device ends up with only congested lanes.
-            ests = np.asarray([drain_estimate(cfg, ln)
-                               for (_, _, cfg), ln in zip(placed, lens)
-                               for _ in range(nv)])
-            order = _deal_order(ests, ndev)
-            inv = np.empty_like(order)
-            inv[order] = np.arange(order.size)
-            t1 = time.perf_counter()
-            d_chunk, d_ratio = drain_sched(placed[0][2])
-            res_perm: List[SimResult] = simulate_batch(
-                placed[0][2], _take_lanes(traffic, order),
-                mc_nodes=mc_rows[order],
-                count_headers=grid.count_headers,
-                chunk=d_chunk, max_cycles=grid.max_cycles,
-                check_conservation=check_conservation, devices=devs,
-                backend=grid.backend, compact_ratio=d_ratio)
-            results = [res_perm[inv[i]] for i in range(len(order))]
-            t2 = time.perf_counter()
+                    with span("noc.packetize.assemble"):
+                        combo_traffics = [
+                            assemble_traffic(payload_cache[pkey], cfg,
+                                             num_streams=mc_pad,
+                                             num_variants=nv, mc_table=tbl)
+                            for (_, _, cfg), tbl in zip(placed, tables)]
+                with span("noc.packetize.assemble"):
+                    parts = [pad_traffic_length(t, t_pad)
+                             for t in combo_traffics]
+                    del combo_traffics
+                    traffic = _concat_lanes(parts)
+                    del parts
+                mc_rows = np.stack(
+                    [np.asarray(tuple(cfg.mc_nodes)
+                                + (0,) * (mc_pad - cfg.num_mcs), np.int32)
+                     for _, _, cfg in placed for _ in range(nv)])
+                # Drain-aware lane order: deal estimate-sorted lanes across
+                # the device shards so no device ends up with only congested
+                # lanes.
+                ests = np.asarray([drain_estimate(cfg, ln)
+                                   for (_, _, cfg), ln in zip(placed, lens)
+                                   for _ in range(nv)])
+                order = _deal_order(ests, ndev)
+                inv = np.empty_like(order)
+                inv[order] = np.arange(order.size)
+            with span("noc.drain"):
+                d_chunk, d_ratio = drain_sched(placed[0][2])
+                res_perm: List[SimResult] = simulate_batch(
+                    placed[0][2], _take_lanes(traffic, order),
+                    mc_nodes=mc_rows[order],
+                    count_headers=grid.count_headers,
+                    chunk=d_chunk, max_cycles=grid.max_cycles,
+                    check_conservation=check_conservation, devices=devs,
+                    backend=grid.backend, compact_ratio=d_ratio)
+                results = [res_perm[inv[i]] for i in range(len(order))]
 
             # Result phase: one independent PE->MC drain per (mesh, model)
             # covering every combo's lanes. Streams inject at the PEs
@@ -593,75 +644,73 @@ def run_sweep(grid: SweepGrid, layers_for_model: LayersFn, *,
             # traffic), so result drains compile once per (mesh, model)
             # rather than once per size group.
             rres: Optional[List[SimResult]] = None
-            t2b = t2
             if grid.result_phase:
-                if model not in rvalue_cache:
-                    rvalue_cache[model] = result_values(
-                        layers, variants,
-                        max_packets_per_layer=grid.max_packets_per_layer)
-                pe_pad = max(c.num_routers - c.num_mcs for c in group)
-                rparts = []
-                for (_, _, cfg), tbl in zip(placed, tables):
-                    rparts.append(build_result_traffic(
-                        layers, cfg, variants,
-                        max_packets_per_layer=grid.max_packets_per_layer,
-                        mc_table=tbl, result_window=grid.result_window,
-                        num_streams=pe_pad, values=rvalue_cache[model],
-                        compression=comp))
-                rnpkts = [int(p.num_packets) for p in rparts]
-                rt_pad = max(int(p.words.shape[-2]) for p in rparts)
-                # Injection-bound estimate per combo (the longest PE
-                # stream floors the drain), dealt across device shards
-                # like the request lanes so no shard holds only the
-                # congested combos.
-                rests = np.asarray([int(np.asarray(p.length).max())
-                                    if p.length.size else 0
-                                    for p in rparts for _ in range(nv)])
-                rtraffic = _concat_lanes(
-                    [pad_traffic_length(p, rt_pad) for p in rparts])
-                del rparts
-                pe_rows = np.stack(
-                    [np.asarray(tuple(cfg.pe_nodes)
-                                + (0,) * (pe_pad - len(cfg.pe_nodes)),
-                                np.int32)
-                     for _, _, cfg in placed for _ in range(nv)])
-                rorder = _deal_order(rests, ndev)
-                rinv = np.empty_like(rorder)
-                rinv[rorder] = np.arange(rorder.size)
-                t2b = time.perf_counter()
-                rres_perm = simulate_batch(
-                    placed[0][2], _take_lanes(rtraffic, rorder),
-                    mc_nodes=pe_rows[rorder],
-                    count_headers=grid.count_headers,
-                    chunk=d_chunk, max_cycles=grid.max_cycles,
-                    check_conservation=check_conservation, devices=devs,
-                    backend=grid.backend, compact_ratio=d_ratio)
-                rres = [rres_perm[rinv[i]] for i in range(len(rorder))]
-            t3 = time.perf_counter()
+                with span("noc.result.packetize"):
+                    if model not in rvalue_cache:
+                        rvalue_cache[model] = result_values(
+                            layers, variants,
+                            max_packets_per_layer=grid.max_packets_per_layer)
+                    pe_pad = max(c.num_routers - c.num_mcs for c in group)
+                    rparts = []
+                    for (_, _, cfg), tbl in zip(placed, tables):
+                        rparts.append(build_result_traffic(
+                            layers, cfg, variants,
+                            max_packets_per_layer=grid.max_packets_per_layer,
+                            mc_table=tbl, result_window=grid.result_window,
+                            num_streams=pe_pad, values=rvalue_cache[model],
+                            compression=comp))
+                    rnpkts = [int(p.num_packets) for p in rparts]
+                    rt_pad = max(int(p.words.shape[-2]) for p in rparts)
+                    # Injection-bound estimate per combo (the longest PE
+                    # stream floors the drain), dealt across device shards
+                    # like the request lanes so no shard holds only the
+                    # congested combos.
+                    rests = np.asarray([int(np.asarray(p.length).max())
+                                        if p.length.size else 0
+                                        for p in rparts for _ in range(nv)])
+                    rtraffic = _concat_lanes(
+                        [pad_traffic_length(p, rt_pad) for p in rparts])
+                    del rparts
+                    pe_rows = np.stack(
+                        [np.asarray(tuple(cfg.pe_nodes)
+                                    + (0,) * (pe_pad - len(cfg.pe_nodes)),
+                                    np.int32)
+                         for _, _, cfg in placed for _ in range(nv)])
+                    rorder = _deal_order(rests, ndev)
+                    rinv = np.empty_like(rorder)
+                    rinv[rorder] = np.arange(rorder.size)
+                with span("noc.result.drain"):
+                    rres_perm = simulate_batch(
+                        placed[0][2], _take_lanes(rtraffic, rorder),
+                        mc_nodes=pe_rows[rorder],
+                        count_headers=grid.count_headers,
+                        chunk=d_chunk, max_cycles=grid.max_cycles,
+                        check_conservation=check_conservation, devices=devs,
+                        backend=grid.backend, compact_ratio=d_ratio)
+                    rres = [rres_perm[rinv[i]] for i in range(len(rorder))]
 
-            pack_s += t1 - t0
-            sim_s += t2 - t1
-            res_pack_s += t2b - t2
-            res_s += t3 - t2b
             class_cycles = sum(r.cycles for r in results)
             stepped_cycles += class_cycles
+            drain_s = log.last("noc.drain")
             entry = {
                 "mesh": mesh_name, "placements": list(grid.placements),
                 "affinity": list(grid.affinity),
                 "model": model, "compression": comp,
                 "variants": len(results),
-                "packetize_s": round(t1 - t0, 4),
-                "simulate_s": round(t2 - t1, 4),
-                "cycles_per_sec": round(class_cycles / (t2 - t1), 1)
-                if t2 > t1 else None,
+                "packetize_s": round(log.last("noc.packetize"), 4),
+                "simulate_s": round(drain_s, 4),
+                "cycles_per_sec": round(class_cycles / drain_s, 1)
+                if drain_s > 0 else None,
             }
             if rres is not None:
                 rc = sum(r.cycles for r in rres)
                 result_cycles += rc
-                entry["result_packetize_s"] = round(t2b - t2, 4)
-                entry["result_simulate_s"] = round(t3 - t2b, 4)
+                rdrain_s = log.last("noc.result.drain")
+                entry["result_packetize_s"] = round(
+                    log.last("noc.result.packetize"), 4)
+                entry["result_simulate_s"] = round(rdrain_s, 4)
                 entry["result_cycles_per_sec"] = (
-                    round(rc / (t3 - t2b), 1) if t3 > t2b else None)
+                    round(rc / rdrain_s, 1) if rdrain_s > 0 else None)
             classes.append(entry)
 
             for pi, (placement, aff, cfg) in enumerate(placed):
@@ -748,37 +797,7 @@ def run_sweep(grid: SweepGrid, layers_for_model: LayersFn, *,
                             (1 - radj / rbase) * 100 if rr else None),
                     })
 
-    wall = pack_s + sim_s + res_pack_s + res_s
-    stats = {
-        "cells": len(rows),
-        "shape_classes": classes,
-        "packetize_s": round(pack_s, 4),
-        # Ordering seconds attributed per transform (one-shot payload-cache
-        # misses only; assembler/simulated time excluded) - lets an O3
-        # chain regression show up against the cheap O0-O2 permutes.
-        "packetize_by_transform": {k: round(v, 4)
-                                   for k, v in sorted(pack_by_tr.items())},
-        "simulate_s": round(sim_s, 4),
-        "wall_s": round(wall, 4),
-        "stepped_cycles": stepped_cycles,
-        "cycles_per_sec": round(stepped_cycles / sim_s, 1) if sim_s else None,
-        "streamed": streamed,
-        "devices": len(devs) if devs else 1,
-        "result_phase": grid.result_phase,
-    }
-    if grid.result_phase:
-        stats["result_packetize_s"] = round(res_pack_s, 4)
-        stats["result_simulate_s"] = round(res_s, 4)
-        stats["result_cycles"] = result_cycles
-        stats["result_cycles_per_sec"] = (
-            round(result_cycles / res_s, 1) if res_s else None)
-    report = SweepReport(rows=rows, stats=stats)
-    if out_path:
-        os.makedirs(os.path.dirname(os.path.abspath(out_path)), exist_ok=True)
-        with open(out_path, "w") as f:
-            json.dump({"grid": _grid_json(grid), "rows": rows,
-                       "stats": stats}, f, indent=1)
-    return report
+    return rows, classes, stepped_cycles, result_cycles
 
 
 def _grid_json(grid: SweepGrid) -> dict:
@@ -818,10 +837,9 @@ def run_serving(grid: SweepGrid, layers_for_model: LayersFn, *,
     nothing (shedding caps queueing, so p50 plateaus by design) - and
     the per-transform BT join ``transforms[tr] = {request_bt, result_bt,
     adjusted_bt, ...}`` at the grid's first precision/tiebreak), and the
-    serving wall-clock.
+    serving wall-clock (the ``noc.serving`` span, which ``stats["spans"]``
+    lists with the sweep's).
     """
-    from .online import ArrivalProcess, latency_percentiles, simulate_online
-
     if not grid.offered_loads:
         raise ValueError("run_serving needs grid.offered_loads (offered "
                          "load points in inferences per 1000 cycles)")
@@ -837,11 +855,30 @@ def run_serving(grid: SweepGrid, layers_for_model: LayersFn, *,
             "run_sweep)")
     base = (grid if grid.result_phase
             else dataclasses.replace(grid, result_phase=True))
-    report = run_sweep(base, layers_for_model,
-                       check_conservation=check_conservation,
-                       devices=devices)
+    with recording() as log:
+        report = run_sweep(base, layers_for_model,
+                           check_conservation=check_conservation,
+                           devices=devices)
+        with span("noc.serving"):
+            serving = _serving_stats(grid, layers_for_model, report,
+                                     check_conservation)
+    serving["serving_s"] = round(log.seconds("noc.serving"), 4)
+    report.stats["serving"] = serving
+    report.stats["spans"] = log.totals()
+    report.stats["counters"] = dict(sorted(log.counters.items()))
+    if out_path:
+        os.makedirs(os.path.dirname(os.path.abspath(out_path)), exist_ok=True)
+        with open(out_path, "w") as f:
+            json.dump({"grid": _grid_json(grid), "rows": report.rows,
+                       "stats": report.stats}, f, indent=1)
+    return report
 
-    t0 = time.perf_counter()
+
+def _serving_stats(grid: SweepGrid, layers_for_model: LayersFn,
+                   report: SweepReport, check_conservation: bool) -> dict:
+    """``stats["serving"]`` of :func:`run_serving`, but its seconds."""
+    from .online import ArrivalProcess, latency_percentiles, simulate_online
+
     o0 = [(by_name(grid.baseline), _QUANTIZERS[grid.precisions[0]])]
     prec0, tb0 = grid.precisions[0], grid.tiebreaks[0]
     loads = sorted(grid.offered_loads)
@@ -978,7 +1015,7 @@ def run_serving(grid: SweepGrid, layers_for_model: LayersFn, *,
                             for a, b in zip(curve, curve[1:])
                             if a is not None and b is not None)
                     combos.append(combo)
-    report.stats["serving"] = {
+    return {
         "offered_loads": loads,
         "inferences": grid.serving_inferences,
         "compute_latency": grid.compute_latency,
@@ -993,11 +1030,4 @@ def run_serving(grid: SweepGrid, layers_for_model: LayersFn, *,
         "admit_queue_depth": grid.admit_queue_depth,
         "points": points,
         "combos": combos,
-        "serving_s": round(time.perf_counter() - t0, 4),
     }
-    if out_path:
-        os.makedirs(os.path.dirname(os.path.abspath(out_path)), exist_ok=True)
-        with open(out_path, "w") as f:
-            json.dump({"grid": _grid_json(grid), "rows": report.rows,
-                       "stats": report.stats}, f, indent=1)
-    return report

@@ -41,6 +41,7 @@ from repro.core.wire import (COMPRESSIONS, WireTransform,
                              compression_overhead_bits)
 from .topology import NocConfig
 from .sim import Traffic, META_PAYLOAD, META_TAIL
+from .spans import span
 
 __all__ = ["LayerTraffic", "build_traffic", "build_traffic_batch",
            "build_traffic_streamed", "build_traffic_streamed_multi",
@@ -232,7 +233,11 @@ def _packet_chunk_fn(transform: WireTransform, lanes: int,
     transform kernel.
     """
     fn = _packet_vmap(transform, lanes, compression)
-    return _placed(transform, jax.jit(lambda i, w: fn(i, w).astype(jnp.uint32)))
+
+    def noc_packet_chunk(i, w):     # the trace's ``jit_noc_packet_chunk``
+        return fn(i, w).astype(jnp.uint32)
+
+    return _placed(transform, jax.jit(noc_packet_chunk))
 
 
 def payload_shapes(
@@ -294,7 +299,9 @@ def ordered_payloads_streamed(
     quantizers are applied to the *whole* layer first (a fixed-point scale
     must not depend on the chunking), and the transform - per-packet by
     construction - runs through one jit-cached vmap whose ragged final
-    chunk is zero-padded to the fixed chunk shape and sliced back.
+    chunk is zero-padded to the fixed chunk shape and sliced back. Each
+    variant's quantization and each chunk's ordering up to host words is
+    a ``noc.packetize.order`` span with its ``transform``.
     """
     if not variants:
         raise ValueError("need at least one (transform, quantizer) variant")
@@ -306,18 +313,21 @@ def ordered_payloads_streamed(
         n = int(inp.shape[0])
         if n == 0:          # nothing to order or scatter for an empty layer
             continue
-        ops = [(inp, wgt) if q is None else (q(inp), q(wgt))
-               for _, q in variants]
+        ops = []
+        for tr, q in variants:
+            with span("noc.packetize.order", transform=tr.name):
+                ops.append((inp, wgt) if q is None else (q(inp), q(wgt)))
         for start in range(0, n, chunk_packets):
             c = min(chunk_packets, n - start)
             per_variant = []
             for (tr, _), (qi, qw) in zip(variants, ops):
-                ci, cw = qi[start:start + c], qw[start:start + c]
-                if c < chunk_packets:
-                    pad = ((0, chunk_packets - c), (0, 0))
-                    ci, cw = jnp.pad(ci, pad), jnp.pad(cw, pad)
-                words = _packet_chunk_fn(tr, lanes, compression)(ci, cw)
-                per_variant.append(np.asarray(words)[:c])
+                with span("noc.packetize.order", transform=tr.name):
+                    ci, cw = qi[start:start + c], qw[start:start + c]
+                    if c < chunk_packets:
+                        pad = ((0, chunk_packets - c), (0, 0))
+                        ci, cw = jnp.pad(ci, pad), jnp.pad(cw, pad)
+                    words = _packet_chunk_fn(tr, lanes, compression)(ci, cw)
+                    per_variant.append(np.asarray(words)[:c])
             shapes = {w.shape for w in per_variant}
             if len(shapes) != 1:
                 raise ValueError(
@@ -789,16 +799,19 @@ def build_traffic_streamed_multi(
         shapes = payload_shapes(layers, cfgs[0].lanes, variants,
                                 max_packets_per_layer=max_packets_per_layer,
                                 compression=compression)
-    asms = [TrafficAssembler(shapes, cfg, num_streams=num_streams,
-                             num_variants=len(variants), mc_table=tbl)
-            for cfg, tbl in zip(cfgs, mc_tables)]
+    with span("noc.packetize.assemble"):
+        asms = [TrafficAssembler(shapes, cfg, num_streams=num_streams,
+                                 num_variants=len(variants), mc_table=tbl)
+                for cfg, tbl in zip(cfgs, mc_tables)]
     for li, start, words in ordered_payloads_streamed(
             layers, cfgs[0].lanes, variants, chunk_packets=chunk_packets,
             max_packets_per_layer=max_packets_per_layer,
             compression=compression):
-        for asm in asms:
-            asm.add_chunk(li, start, words)
-    return [asm.finish() for asm in asms]
+        with span("noc.packetize.assemble"):
+            for asm in asms:
+                asm.add_chunk(li, start, words)
+    with span("noc.packetize.assemble"):
+        return [asm.finish() for asm in asms]
 
 
 def build_traffic_batch(
