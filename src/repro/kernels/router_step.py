@@ -1,9 +1,11 @@
 """Pallas kernel: one NoC router cycle over the whole mesh state.
 
-This is the per-cycle hot loop of ``repro.noc.sim`` - sideband front
-gather, X-Y route computation, credit check, masked-min round-robin
+One cycle of ``repro.noc.sim``'s router step - sideband front gather,
+X-Y route computation, credit check, masked-min round-robin
 arbitration, the combined push+inject scatter, and the Fig. 8 BT
-accumulate - as a single ``pl.pallas_call`` body. The surrounding scan,
+accumulate - as a single ``pl.pallas_call`` body. It keeps the gather
+and scatter form; the fused step now reads and writes its FIFOs with
+one-hot selects instead. The surrounding scan,
 the injection-row gather from the (M, T, LF) wire tensor (which cannot
 live in VMEM at DarkNet scale), and the drain bookkeeping stay in
 ``noc.sim``; the kernel sees one cycle's state plus the M pre-gathered
@@ -16,10 +18,8 @@ on any chip path: the TPU's Mosaic compiler rejects its gathers ("Only
 2D gather is supported") at every mesh size, so ``make_router_step``
 refuses ``interpret=False`` with :data:`TPU_UNSUPPORTED`, and
 ``noc.sim``'s ``backend="auto"`` resolves to the fused jnp step on every
-platform. The arithmetic is copied from ``noc.sim._make_step`` op for op
-- same gathers, same select chains, same scatter - so the two backends
-are bit-identical by construction, and the parity tests keep them that
-way. The BT recorder uses the SWAR popcount form
+platform. Its results are bit-identical to ``noc.sim._make_step``,
+which ``tests/test_kernel_parity.py`` pins. The BT recorder uses the SWAR popcount form
 (``kernels/popcount.py``) instead of ``lax.population_count``; the two
 are pinned equal by the kernel parity suite.
 
@@ -68,8 +68,9 @@ def _popcount(x: jax.Array) -> jax.Array:
 def _make_kernel(mesh_key, count_headers: bool, lf: int, m: int):
     """Build the kernel body for one (mesh size, recorder, stream count).
 
-    All routing geometry is baked in as compile-time constants exactly as
-    in ``noc.sim._make_step`` (same derivation, same names).
+    All routing geometry is baked in as compile-time constants: the
+    downstream blocks and incoming map that ``noc.sim._make_step`` reads
+    by shifts of the router axis, here as index tables.
     """
     rows, cols, num_vcs, vc_depth, lanes = mesh_key
     cfg = NocConfig(rows, cols, (), num_vcs=num_vcs, vc_depth=vc_depth,
@@ -83,10 +84,9 @@ def _make_kernel(mesh_key, count_headers: bool, lf: int, m: int):
     def _geometry():
         """Routing constants, derived in-kernel from iota arithmetic.
 
-        Pallas kernels cannot capture array constants, so the tables
-        ``noc.sim._make_step`` bakes in as numpy arrays are recomputed
-        here elementwise - same values (checked by the parity suite),
-        zero inputs. Directions 0..3 are N, E, S, W; ``OPPOSITE`` for
+        Pallas kernels cannot capture array constants, so the index
+        tables are computed here elementwise - zero inputs, results
+        checked by the parity suite. Directions 0..3 are N, E, S, W; ``OPPOSITE`` for
         them is ``(dir + 2) % 4``.
         """
         cid3 = jax.lax.broadcasted_iota(jnp.int32, (nr, 1, 1), 0)

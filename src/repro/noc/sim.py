@@ -24,12 +24,13 @@ Simplifications (documented in DESIGN.md):
 
 Fused-state hot loop (see DESIGN.md "Fused router step"): the per-flit
 sideband (dest | META | VC) is packed into one uint32 word and stacked with
-the payload lanes, so the per-cycle FIFO traffic is one sideband gather,
-one winner-flit gather, and one combined push+inject scatter; X-Y routing,
-port opposition, neighbor lookup, and the credit/count bookkeeping are
-closed-form coordinate arithmetic and *static-index* gathers (XLA:CPU
-lowers dynamic table gathers and scatters to scalar loops - they were most
-of the cycle time); the BT recorder runs through
+the payload lanes, and every read and write of the FIFO, head and count
+state is a one-hot select over a static axis (depth, the router's slots,
+the M streams) - no gather or scatter, which a TPU runs as a serialized
+loop and which took most of the cycle there; X-Y routing, port
+opposition, and neighbour lookup are closed-form coordinate arithmetic
+and shifts of the router axis seen as (rows, cols); the BT recorder runs
+through
 ``jax.lax.population_count`` (the SWAR form in ``repro.core.bits`` stays
 the oracle); and the per-packet conservation ledger exists only under
 ``check_conservation=True``. The pre-overhaul step survives verbatim in
@@ -160,7 +161,9 @@ def fuse_traffic(traffic: Traffic, track_pkt: bool = False) -> Wire:
 
 class SimState(NamedTuple):
     # Fused FIFO contents: payload lanes | sideband | optional pkt lane.
-    # Router axis padded by one phantom row absorbing masked-out scatters.
+    # Router axis padded by one phantom row, kept for the drivers and the
+    # Pallas kernel (its dump slot for masked-out scatters); the fused step
+    # never writes it.
     fifo: jax.Array    # (NR+1, P, V, D, LF) uint32
     head: jax.Array    # (NR+1, P, V) int32
     count: jax.Array   # (NR+1, P, V) int32
@@ -383,22 +386,30 @@ def _make_step(mesh_key, count_headers: bool, track: bool,
     production step is byte-identical with the flag off.
 
     Bit-identical to the pre-overhaul step (``repro.noc._reference``,
-    pinned by tests/test_noc_step.py) with the hot-path structure changed:
+    pinned by tests/test_noc_step.py, every state leaf in a congested
+    8x8 batch) with the hot-path structure changed. No read or write of
+    the FIFO, head or count state carries an index computed from data:
 
-    * one front gather of the packed sideband word per FIFO (the payload is
-      gathered only for the <= NR*P switch winners, not every FIFO slot);
+    * the front flit of every FIFO is a one-hot select over the depth
+      axis (``head == d``), and the winners' flits a one-hot select over
+      the router's P*V slots;
     * X-Y routing, port opposition, and downstream-router lookup are
-      coordinate arithmetic / compile-time constants - XLA:CPU lowers table
-      gathers to scalar loops, and these were half the cycle time;
-    * the credit check gathers every neighbor's input-FIFO counts with a
-      *static* index array (the four downstream blocks per router are fixed
-      by the mesh) and selects by out_port elementwise;
+      coordinate arithmetic / compile-time constants;
+    * the credit check reads every neighbour's facing input-FIFO counts
+      by shifts of the router axis (the four downstream blocks per router
+      are fixed by the mesh) and selects by out_port elementwise; the
+      incoming flits and VCs are the same shifts of the winners;
     * round-robin arbitration picks winners by a masked min over the
       rotation distance instead of gathering a rotated request matrix;
-    * pushes and injections write one combined scatter (their FIFO targets
-      are provably disjoint: pushes never write local in-ports), and the
-      FIFO-count increments are reconstructed receiver-side from another
-      static-index gather instead of a second scatter.
+    * pushes write in-ports 0-3 with one masked select, one-hot on
+      (incoming VC, write slot); injections write the local in-port with
+      another, one-hot on (router, VC, slot) and reduced over the streams;
+      the count increments are the same masks.
+
+    The only gather left in the untracked step is the per-stream
+    injection read from the wire. The earlier row gathers and the row
+    scatter suited XLA:CPU; on a TPU they were ~109 of ~143 us a cycle
+    at 8x8 with 3 lanes (PERF.md section 5).
     """
     if timestamps and not track:
         raise ValueError("timestamps=True requires track=True (the ledgers "
@@ -407,38 +418,33 @@ def _make_step(mesh_key, count_headers: bool, track: bool,
     cfg = NocConfig(rows, cols, (), num_vcs=num_vcs, vc_depth=vc_depth,
                     lanes=lanes)    # mc-free view: routing/geometry only
     nr, p, v, d, l = cfg.num_routers, NUM_PORTS, cfg.num_vcs, cfg.vc_depth, cfg.lanes
-    lf = l + 1 + (1 if track else 0)
     nslots = p * v
 
     # Compile-time routing constants (replacing the route/neighbor tables).
     coords = np.arange(nr)
-    rrow_np, rcol_np = coords // cols, coords % cols
-    rrow = jnp.asarray(rrow_np[:, None, None], jnp.int32)   # (NR, 1, 1)
-    rcol = jnp.asarray(rcol_np[:, None, None], jnp.int32)
-    # Downstream router per (router, out_port); phantom row nr at mesh edges
-    # (the direction a port faces is static, only *whether* a flit goes
-    # there is dynamic).
-    delta_np = np.array([-cols, 1, cols, -1, 0])
-    down_np = coords[:, None] + delta_np[None, :]
-    dir_ok = np.stack([rrow_np > 0, rcol_np < cols - 1, rrow_np < rows - 1,
-                       rcol_np > 0, np.zeros(nr, bool)], axis=1)
-    down_o = jnp.asarray(np.where(dir_ok, down_np, nr), jnp.int32)  # (NR, P)
-    opp4 = OPPOSITE[:4]                  # in/out opposition, non-local ports
-    # Credit blocks: for output direction o of router r, the downstream
-    # input FIFO block (nbr(r,o), opp(o)); phantom block (always count 0)
-    # where the direction leaves the mesh.
-    nb_blk = jnp.asarray(
-        np.where(dir_ok[:, :4], down_np[:, :4] * NUM_PORTS + opp4[None, :],
-                 nr * NUM_PORTS), jnp.int32)                        # (NR, 4)
-    # Receiver-centric incoming map: in-port ip of router r receives the
-    # winner of output opp(ip) at neighbor nbr(r, ip), when it exists.
-    src_ok = jnp.asarray(dir_ok[:, :4])                             # (NR, 4)
-    src_po = jnp.asarray(
-        np.where(dir_ok[:, :4], down_np[:, :4], 0) * NUM_PORTS
-        + opp4[None, :], jnp.int32)                                 # (NR, 4)
-    rcv_base = jnp.asarray(
-        coords[:, None] * NUM_PORTS + np.arange(4)[None, :], jnp.int32)
-    phantom_row = nr * NUM_PORTS * num_vcs * vc_depth
+    rrow = jnp.asarray((coords // cols)[:, None, None], jnp.int32)  # (NR,1,1)
+    rcol = jnp.asarray((coords % cols)[:, None, None], jnp.int32)
+    r_ids = jnp.arange(nr, dtype=jnp.int32)
+    depth = jnp.arange(d, dtype=jnp.int32)
+    vcs = jnp.arange(v, dtype=jnp.int32)
+
+    def from_nbrs(x):
+        """``(NR, P, ...) -> (NR, 4, ...)``: entry ``(r, q)`` is ``x`` at
+        the neighbour of ``r`` in direction ``q``, on the port facing back
+        (``OPPOSITE[q]``); zero where the direction leaves the mesh.
+
+        The neighbour in a direction is fixed by the mesh, so this is four
+        shifts of the router axis seen as (rows, cols), not a gather.
+        """
+        g = x.reshape((rows, cols) + x.shape[1:])
+        z_row = jnp.zeros_like(g[:1])
+        z_col = jnp.zeros_like(g[:, :1])
+        nbr = {PORT_N: jnp.concatenate([z_row, g[:-1]], axis=0),
+               PORT_E: jnp.concatenate([g[:, 1:], z_col], axis=1),
+               PORT_S: jnp.concatenate([g[1:], z_row], axis=0),
+               PORT_W: jnp.concatenate([z_col, g[:, :-1]], axis=1)}
+        return jnp.stack([nbr[q][:, :, int(OPPOSITE[q])] for q in range(4)],
+                         axis=2).reshape((nr, 4) + x.shape[2:])
 
     # --- fault-injection trace-time constants (None: no fault code at all)
     flips_on = False
@@ -465,22 +471,17 @@ def _make_step(mesh_key, count_headers: bool, track: bool,
     def step(state: SimState, wire: Wire, mc_nodes: jax.Array):
         m = wire.length.shape[0]
         t_cap = wire.wire.shape[1]
+        lf = state.fifo.shape[-1]
         flip_pkt, bad_pkt = state.flip_pkt, state.bad_pkt
         head_r = state.head[:nr]                           # (NR, P, V)
         count_r = state.count[:nr]
         valid = count_r > 0
 
-        # Row-flat FIFO views: gathers/scatters move whole LF-word rows
-        # (one contiguous memcpy per flit) instead of per-element loops.
-        fifo_rows = state.fifo.reshape((nr + 1) * p * v * d, lf)
-
-        # --- front sideband: one word per FIFO ---
-        side_col = fifo_rows[:, l]                         # strided slice
-        front_row = (jnp.arange(nr * p * v, dtype=jnp.int32) * d
-                     + head_r.reshape(-1))
-        fside = jnp.take(side_col, front_row,
-                         mode="clip").astype(jnp.int32)
-        fside = fside.reshape(nr, p, v)
+        # --- front flit of every FIFO: one-hot select over the depth ---
+        at_head = state.head[..., None] == depth           # (NR+1, P, V, D)
+        front = jnp.where(at_head[..., None], state.fifo,
+                          jnp.uint32(0)).max(axis=3)[:nr]  # (NR, P, V, LF)
+        fside = front[..., l].astype(jnp.int32)
         fd = fside & _DEST_MASK                            # (NR, P, V)
 
         # --- route computation (X-Y, closed form) ---
@@ -495,20 +496,16 @@ def _make_step(mesh_key, count_headers: bool, track: bool,
             # Detour table: X-Y where intact, BFS-descending around dead
             # links (equal to X-Y entry-for-entry when no hard faults).
             # Garbage dests of empty FIFOs are masked by ``valid`` below.
-            r_ids = jnp.arange(nr, dtype=jnp.int32)[:, None, None]
-            out_port = jnp.take(froute, r_ids * nr + jnp.minimum(fd, nr - 1),
-                                mode="clip")
+            out_port = jnp.take(froute, r_ids[:, None, None] * nr
+                                + jnp.minimum(fd, nr - 1), mode="clip")
 
         # --- credit check: downstream FIFO (same VC) has space ---
-        # One static-index gather of every neighbor's input-FIFO counts
-        # (the four possible downstream blocks per router are fixed by the
-        # mesh), then an elementwise select by the flit's out_port. X-Y
-        # routing never points off-mesh for a real flit, and ejection needs
-        # no credit.
+        # Every neighbour's facing input-FIFO counts, then an elementwise
+        # select by the flit's out_port. Off the mesh the count reads 0:
+        # X-Y routing never points there for a real flit, and ejection
+        # needs no credit.
         is_eject = out_port == PORT_LOCAL
-        count_blocks = state.count.reshape((nr + 1) * p, v)
-        ok = (jnp.take(count_blocks, nb_blk.reshape(-1), axis=0,
-                       mode="clip").reshape(nr, 4, v) < d)  # (NR, dir, V)
+        ok = from_nbrs(count_r) < d                        # (NR, dir, V)
         space = jnp.where(
             out_port == PORT_N, ok[:, None, PORT_N, :], jnp.where(
                 out_port == PORT_E, ok[:, None, PORT_E, :], jnp.where(
@@ -535,23 +532,15 @@ def _make_step(mesh_key, count_headers: bool, track: bool,
         rr_new = jnp.where(has, rr_new, state.rr)
 
         # --- pops ---
-        pop = ((slots == winner[:, :, None]) & has[:, :, None]).any(axis=1)
-        pop = pop.reshape(nr, p, v)                         # (NR, P, V)
+        won = slots == winner[:, :, None]                  # (NR, P_out, PV)
+        pop = (won & has[:, :, None]).any(axis=1).reshape(nr, p, v)
         head_new = jnp.where(pop, (head_r + 1) % d, head_r)
         count_new = count_r - pop.astype(jnp.int32)
-        head2 = state.head.at[:nr].set(head_new)
-        count2 = state.count.at[:nr].set(count_new)
 
-        # --- gather the winners' flits only: (NR, P_out, LF) ---
-        win_p = winner // v
+        # --- the winners' flits: one-hot select over the router's slots ---
         win_v = winner % v
-        r2 = jnp.arange(nr, dtype=jnp.int32)[:, None]
-        win_pv = (r2 * p + win_p) * v + win_v              # (NR, P_out)
-        win_head = jnp.take(state.head.reshape(-1), win_pv.reshape(-1),
-                            mode="clip")
-        win_row = win_pv.reshape(-1) * d + win_head
-        mv = jnp.take(fifo_rows, win_row, axis=0,
-                      mode="clip").reshape(nr, p, lf)
+        mv = jnp.where(won[..., None], front.reshape(nr, 1, nslots, lf),
+                       jnp.uint32(0)).max(axis=2)          # (NR, P_out, LF)
         if flips_on:
             # Transient per-link soft error: hash (seed, cycle, link id)
             # into a uniform word; a hit XORs one payload bit of the flit
@@ -588,22 +577,17 @@ def _make_step(mesh_key, count_headers: bool, track: bool,
 
         # --- pushes, receiver-side ---
         # In-port ip of router r receives the winner of output opp(ip) at
-        # neighbor nbr(r, ip) - a *static* mapping, so the incoming flit,
-        # its VC, and the write slot all come from static-index gathers and
-        # local elementwise math; no dynamic sender->receiver indexing.
+        # neighbor nbr(r, ip): a mapping fixed by the mesh, so the incoming
+        # flit and its VC are shifts of the sender's arrays, and the write
+        # is a one-hot mask on (VC, slot); no sender->receiver indexing.
         o_ids = jnp.arange(NUM_PORTS)[None, :]
-        inc_ok = (jnp.take(has.reshape(-1), src_po.reshape(-1), mode="clip")
-                  .reshape(nr, 4) & src_ok)
-        inc_vc = jnp.take(win_v.reshape(-1), src_po.reshape(-1),
-                          mode="clip").reshape(nr, 4)
-        inc_w = jnp.take(mv.reshape(nr * p, lf), src_po.reshape(-1),
-                         axis=0, mode="clip")               # (NR*4, LF)
-        # Write slot per (router, in-port): (head + count) of the incoming
-        # VC's FIFO, selected elementwise over the V axis.
-        wc4 = (head2[:nr, :4, :] + count2[:nr, :4, :]) % d   # (NR, 4, V)
-        wslot = wc4[..., 0]
-        for vi in range(1, v):      # static V-way select, no gather
-            wslot = jnp.where(inc_vc == vi, wc4[..., vi], wslot)
+        inc_ok = from_nbrs(has)                            # (NR, 4)
+        inc_vc = from_nbrs(win_v)
+        inc_w = from_nbrs(mv)                              # (NR, 4, LF)
+        push = inc_ok[..., None] & (inc_vc[..., None] == vcs)  # (NR, 4, V)
+        # Write slot of every in-port FIFO: (head + count) after the pops.
+        wslot = (head_new[:, :4] + count_new[:, :4]) % d   # (NR, 4, V)
+        put = push[..., None] & (wslot[..., None] == depth)  # (NR, 4, V, D)
         ejected = state.ejected + jnp.sum(has & (o_ids == PORT_LOCAL))
 
         # --- conservation ledger: tail flits ejecting at their PE ---
@@ -662,19 +646,20 @@ def _make_step(mesh_key, count_headers: bool, track: bool,
             ivc = (iside >> SIDE_VC_SHIFT) & (MAX_VCS - 1)
         else:
             ivc = iside >> SIDE_VC_SHIFT
-        # Pushes never touch local in-ports, so the local-port counts in
-        # ``count2`` are already post-push values: injection composes with
-        # the push scatter below without an intermediate count array.
-        head2_flat = head2.reshape(-1)
-        count2_flat = count2.reshape(-1)
-        mc_pv = (mc_nodes * p + PORT_LOCAL) * v + ivc
-        mc_cnt = jnp.take(count2_flat, mc_pv, mode="clip")
+        # Each stream's local in-port FIFO, one-hot on (router, VC). Pushes
+        # never touch local in-ports, so the post-pop local counts are the
+        # ones injection sees.
+        at_mc = ((mc_nodes[:, None] == r_ids)[:, :, None]
+                 & (ivc[:, None, None] == vcs))            # (M, NR, V)
+        head_loc = head_new[:, PORT_LOCAL]                 # (NR, V)
+        count_loc = count_new[:, PORT_LOCAL]
+        mc_cnt = jnp.where(at_mc, count_loc, 0).max(axis=(1, 2))
         can = active & (mc_cnt < d)
         if flips_on:
             # NI-link soft error: the flit entering the mesh this cycle is
             # a flit-hop too. Same hash family, link ids offset past the
-            # router links. Applied before the combined scatter and the NI
-            # BT recorder below.
+            # router links. Applied before the FIFO write and the NI BT
+            # recorder below.
             ilid = jnp.arange(m, dtype=jnp.uint32) + jnp.uint32(nr * p)
             ih = _mix32(_mix32(ilid + flip_seed)
                         ^ (cyc_u * jnp.uint32(0x9E3779B9)))
@@ -687,26 +672,33 @@ def _make_step(mesh_key, count_headers: bool, track: bool,
             imask = jnp.where((ilanes == ihit_lane[:, None]) & ihit[:, None],
                               ihit_word[:, None], jnp.uint32(0))
             iw = jnp.concatenate([iw[..., :l] ^ imask, iw[..., l:]], axis=-1)
-        inj_pv = jnp.where(can, mc_pv, (nr * p + PORT_LOCAL) * v + ivc)
-        islot = (jnp.take(head2_flat, inj_pv, mode="clip")
-                 + jnp.take(count2_flat, inj_pv, mode="clip")) % d
 
-        # --- one combined push+inject scatter (disjoint FIFO targets) ---
-        rcv_row = jnp.where(inc_ok, (rcv_base * v + inc_vc) * d + wslot,
-                            phantom_row)
-        cat_row = jnp.concatenate([rcv_row.reshape(-1), inj_pv * d + islot])
-        cat_w = jnp.concatenate([inc_w, iw])
-        fifo_new = fifo_rows.at[cat_row].set(
-            cat_w, mode="promise_in_bounds").reshape(state.fifo.shape)
-        # Count increments ride the same receiver-side masks: a one-hot VC
-        # add instead of scattering increment rows (XLA:CPU scatters cost
-        # ~5x a same-size gather).
-        vcs4 = jnp.arange(v, dtype=jnp.int32)[None, None, :]
-        count_inc = ((vcs4 == inc_vc[..., None])
-                     & inc_ok[..., None]).astype(jnp.int32)  # (NR, 4, V)
-        count_new = count2.at[:nr, :4, :].add(count_inc).reshape(-1).at[
-            inj_pv].add(can.astype(jnp.int32),
-                        mode="promise_in_bounds").reshape(count2.shape)
+        # --- injection write: reduce the streams onto (router, VC) ---
+        # MC routers are distinct and padding streams never inject, so at
+        # most one stream lands on any (router, VC).
+        ins = at_mc & can[:, None, None]                   # (M, NR, V)
+        inj_w = jnp.where(ins[..., None], iw[:, None, None, :],
+                          jnp.uint32(0)).max(axis=0)       # (NR, V, LF)
+        inj_inc = ins.sum(axis=0, dtype=jnp.int32)         # (NR, V)
+        islot = (head_loc + count_loc) % d
+        put_loc = (inj_inc > 0)[..., None] & (islot[..., None] == depth)
+
+        # --- FIFO writes: one masked select over the whole FIFO ---
+        # Pushes fill in-ports 0-3, injections the local in-port; the
+        # phantom row's mask is all false, so no write needs its dump slot.
+        put_all = jnp.concatenate([put, put_loc[:, None]], axis=1)
+        w_all = jnp.concatenate(
+            [jnp.broadcast_to(inc_w[:, :, None, :], (nr, 4, v, lf)),
+             inj_w[:, None]], axis=1)                      # (NR, P, V, LF)
+        no_row = ((0, 1), (0, 0), (0, 0), (0, 0))          # the phantom row
+        fifo_new = jnp.where(jnp.pad(put_all, no_row)[..., None],
+                             jnp.pad(w_all, no_row)[:, :, :, None, :],
+                             state.fifo)
+        head_out = jnp.concatenate([head_new, state.head[nr:]], axis=0)
+        count_inc = jnp.concatenate(
+            [push.astype(jnp.int32), inj_inc[:, None]], axis=1)
+        count_out = jnp.concatenate([count_new + count_inc, state.count[nr:]],
+                                    axis=0)
         ptr_new = ptr + can.astype(jnp.int32)
 
         # NI-link BT (MC -> router); the ordering unit sits right before it.
@@ -738,7 +730,7 @@ def _make_step(mesh_key, count_headers: bool, track: bool,
         drained_at = jnp.where((state.drained_at < 0) & (ejected >= total),
                                state.cycle + 1, state.drained_at)
 
-        return SimState(fifo_new, head2, count_new, rr_new, link_last,
+        return SimState(fifo_new, head_out, count_out, rr_new, link_last,
                         link_bt, link_flits, ptr_new, inj_last, inj_bt,
                         ejected, state.cycle + 1, eject_pkt, drained_at,
                         inj_time, eject_time, flip_pkt, bad_pkt)
@@ -782,10 +774,9 @@ def _make_step_pallas(mesh_key, count_headers: bool, track: bool):
     """The per-cycle step routed through the Pallas router kernel.
 
     Same (state, wire, mc_nodes) signature and bit-identical results as
-    :func:`_make_step` (pinned by tests/test_kernel_parity.py): the kernel
-    body copies the fused step's arithmetic op for op. Only the injection
-    row gather stays outside - the (M, T, LF) wire tensor cannot live in
-    VMEM at DarkNet scale, and a one-row-per-stream dynamic slice is
+    :func:`_make_step` (pinned by tests/test_kernel_parity.py). Only the
+    injection row gather stays outside - the (M, T, LF) wire tensor cannot
+    live in VMEM at DarkNet scale, and a one-row-per-stream dynamic slice is
     exactly what XLA already does well.
     """
     from repro.kernels.router_step import router_step_pallas
@@ -904,12 +895,12 @@ def _conservation_error(length: np.ndarray, meta: np.ndarray,
 
 
 def _validate_fields(cfg: NocConfig, traffic: Traffic) -> None:
-    """Range-check the fields that feed packed sidebands and
-    promise-in-bounds scatters.
+    """Range-check the fields that feed packed sidebands and the one-hot
+    FIFO writes.
 
     The packetizer satisfies these by construction; hand-built Traffic
     (or traffic packetized for a different config) must fail loudly here
-    rather than corrupt the fused scatter. One device-side reduction per
+    rather than corrupt the FIFO state. One device-side reduction per
     drain call - no host pull of the full tensors.
     """
     if not traffic.dest.size:

@@ -4,7 +4,8 @@ Pins the fused-state router step (packed sideband lane, closed-form
 routing, receiver-side pushes, optional conservation ledger, hardware
 popcount recorder) bit-for-bit against the frozen PR-3 unfused step
 (``repro.noc._reference.simulate_unfused``) on the full 36-cell pinned
-reference grid, and the drain scheduler's variant retirement/compaction
+reference grid plus one congested 8x8/MC8 batch compared state leaf by
+state leaf, and the drain scheduler's variant retirement/compaction
 against the plain batched drain.
 """
 import jax
@@ -21,8 +22,10 @@ from repro.noc import (PAPER_NOCS, LayerTraffic, NocConfig, SweepGrid,
                        make_noc, mesh_by_name, run_sweep, simulate,
                        simulate_batch)
 from repro.noc.sim import (META_PAYLOAD, META_TAIL, SIDE_META_SHIFT,
-                           SIDE_VC_SHIFT, _DEST_MASK, fuse_traffic,
+                           SIDE_VC_SHIFT, _DEST_MASK, _chunk_runner,
+                           _mesh_key, fuse_traffic, make_state,
                            pack_sideband)
+from repro.noc import _reference
 from repro.noc._reference import simulate_unfused
 from repro.noc.sweep import _deal_order, drain_estimate
 from repro.noc.topology import mean_hop_counts
@@ -37,6 +40,10 @@ PINNED_PRECISIONS = ("float32", "fixed8")
 PINNED_TIEBREAKS = ("stable", "pattern")
 PINNED_ORDERINGS = ("O0", "O1", "O2")
 MAX_PACKETS = 8
+# One more case of the parity test: the benchmark's 8x8/MC8 mesh with three
+# vmapped lanes of hotspot traffic, so credits bind and MC injections stall
+# on full local FIFOs; every SimState leaf is compared, not just results.
+CONGESTED = "8x8_mc8_congested_x3"
 
 
 @pytest.fixture(scope="module")
@@ -94,10 +101,102 @@ def test_fuse_traffic_layout(pinned_layers):
         np.asarray(tr.pkt))
 
 
-@pytest.mark.parametrize("mesh", PINNED_MESHES)
+def _hotspot_batch(cfg, lanes=3, packets=40, payload=4, seed=0):
+    """(lanes, M, T) Traffic: every MC stream sends ``packets`` packets of
+    one header and ``payload`` payload flits to a few hotspot routers, VCs
+    dealt round-robin, stream lengths varied per MC."""
+    rng = np.random.default_rng(seed)
+    m, nr, per = cfg.num_mcs, cfg.num_routers, payload + 1
+    t = packets * per
+    meta = np.full(per, META_PAYLOAD, np.int32)
+    meta[0], meta[-1] = 0, META_PAYLOAD | META_TAIL
+    hot = [np.array([nr - 1]), np.array([0, nr - 1]), rng.choice(nr, 3)]
+    dest = np.zeros((lanes, m, t), np.int32)
+    vc = np.zeros((lanes, m, t), np.int32)
+    pkt = np.zeros((lanes, m, t), np.int32)
+    for b in range(lanes):
+        for i in range(m):
+            ids = i * packets + np.arange(packets)
+            dest[b, i] = np.repeat(rng.choice(hot[b % 3], packets), per)
+            vc[b, i] = np.repeat(ids % cfg.num_vcs, per)
+            pkt[b, i] = np.repeat(ids, per)
+    length = np.tile(t - per * (np.arange(m) % 3), (lanes, 1))
+    words = rng.integers(0, 2**32, (lanes, m, t, cfg.lanes), dtype=np.uint32)
+    return Traffic(jnp.asarray(words), jnp.asarray(dest),
+                   jnp.asarray(np.tile(meta, (lanes, m, packets))),
+                   jnp.asarray(vc), jnp.asarray(pkt),
+                   jnp.asarray(length, jnp.int32), num_packets=m * packets)
+
+
+def _assert_congested_state_parity(chunk=128, chunks=3):
+    """Step the fused drain (untracked and tracked) and the unfused
+    reference side by side and compare every SimState leaf at each chunk
+    boundary. The phantom FIFO row is the reference's dump slot for
+    masked-out scatters, so only real routers' FIFO contents compare."""
+    cfg = make_noc(8, 8, 8, "interleaved")
+    tr = _hotspot_batch(cfg)
+    lanes, m = tr.length.shape
+    nr, l, d = cfg.num_routers, cfg.lanes, cfg.vc_depth
+    npkt = tr.num_packets
+    mc = jnp.asarray(cfg.mc_nodes, jnp.int32)
+    key = _mesh_key(cfg)
+    stack = lambda st: jax.tree.map(  # noqa: E731
+        lambda x: jnp.stack([x] * lanes), st)
+    ref_run = _reference._unfused_chunk_runner(key, True, chunk, True)
+    ref = stack(_reference.make_state(cfg, m, npkt))
+    runs = {}
+    for track in (False, True):
+        runs[track] = (_chunk_runner(key, True, chunk, True, track, "fused"),
+                       stack(make_state(cfg, m, npkt=npkt if track else 0)),
+                       fuse_traffic(tr, track))
+    mcb = jnp.broadcast_to(mc, (lanes, m))
+    vidx = np.arange(cfg.num_vcs)[None, None, None, :, None]
+    full_link = full_local = stalled = False
+    for k in range(chunks):
+        ref = ref_run(ref, tr, mc)
+        want = jax.tree.map(np.asarray, ref._asdict())
+        cnt = want["count"]
+        full_link |= bool((cnt[:, :nr, :4] == d).any())
+        full_local |= bool((cnt[:, list(cfg.mc_nodes), 4] == d).any())
+        stalled |= bool((want["inj_ptr"] < np.minimum(
+            want["cycle"][:, None], np.asarray(tr.length))).any())
+        for track in (False, True):
+            run, st, wire = runs[track]
+            st, _ = run(st, wire, mcb)
+            runs[track] = (run, st, wire)
+            got = jax.tree.map(np.asarray, st._asdict())
+            where = (k, track)
+            for leaf in ("head", "count", "rr", "link_last", "link_bt",
+                         "link_flits", "inj_ptr", "inj_last", "inj_bt",
+                         "ejected", "cycle", "drained_at"):
+                assert np.array_equal(got[leaf], want[leaf]), (leaf, where)
+            fifo = got["fifo"][:, :nr]
+            side = fifo[..., l].astype(np.int32)
+            assert np.array_equal(fifo[..., :l], want["words"][:, :nr]), where
+            assert np.array_equal(side & _DEST_MASK,
+                                  want["dest"][:, :nr]), where
+            assert np.array_equal((side >> SIDE_META_SHIFT) & 3,
+                                  want["meta"][:, :nr]), where
+            # a written slot carries its FIFO's VC; an unwritten one is 0
+            assert (((side >> SIDE_VC_SHIFT) == vidx)
+                    | (side == 0)).all(), where
+            if track:
+                assert np.array_equal(fifo[..., l + 1].astype(np.int32),
+                                      want["pkt"][:, :nr]), where
+                assert np.array_equal(got["eject_pkt"],
+                                      want["eject_pkt"]), where
+    assert int(want["ejected"].min()) < int(np.asarray(tr.length).sum(1).min())
+    assert full_link and full_local and stalled
+
+
+@pytest.mark.parametrize("mesh", PINNED_MESHES + (CONGESTED,))
 def test_fused_step_bit_identical_to_unfused(pinned_layers, mesh):
     """All 36 pinned reference cells: total_bt, link_bt, AND the exact
-    drain_cycle of the fused step equal the frozen PR-3 unfused step."""
+    drain_cycle of the fused step equal the frozen PR-3 unfused step; and
+    in the congested batch every state leaf equals the reference's."""
+    if mesh == CONGESTED:
+        _assert_congested_state_parity()
+        return
     cfg = mesh_by_name(mesh)
     for prec in PINNED_PRECISIONS:
         for tb in PINNED_TIEBREAKS:

@@ -4,8 +4,10 @@ Nothing here runs on a chip: each case lowers and compiles a jitted
 program for a v5e that is described (``topologies.get_topology_desc``)
 and not attached, so the TPU compiler refuses here what it would refuse
 on the chip. Covered: the fused chunk runner at the paper and DarkNet
-mesh sizes, batched and not; the O3 chain at a DarkNet layer's window
-stack; and the variants-sharded runner on a 4-chip mesh. The router
+mesh sizes, batched and not; the router step's FIFO reads and writes
+(no gather or scatter but the injection read from the wire); the O3
+chain at a DarkNet layer's window stack; and the variants-sharded runner
+on a 4-chip mesh. The router
 kernel's guard is pinned too: Mosaic rejects its gathers, so asking for
 a TPU build raises a clear ValueError.
 
@@ -13,6 +15,7 @@ The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -85,6 +88,27 @@ def test_fused_chunk_runner_compiles_for_v5e(one_chip, mesh, t, chunk,
     mem = compiled.memory_analysis()
     if mem is not None:      # a v5e chip holds 16 GB of HBM
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_untracked_step_has_no_fifo_gather_or_scatter(one_chip):
+    """The benchmark's drain (8x8/MC8, 3 lanes, 2,048-cycle chunks) holds
+    one gather, the per-stream injection read whose operand is the wire,
+    and no scatter: the FIFO, head and count state are read and written
+    by one-hot selects. On a v5e each serialized gather or scatter there
+    cost several us a cycle."""
+    cfg = mesh_by_name("8x8_mc8")
+    batch, t = 3, 8192
+    run = _chunk_runner(_mesh_key(cfg), True, 2048, True, False, "fused")
+    text = run.lower(*_drain_args(cfg, cfg.num_mcs, t, batch,
+                                  one_chip)).compile().as_text()
+    shapes = dict(re.findall(r"(%[\w.-]+) = (\w+\[[\d,]*\])", text))
+    ops = re.findall(r"(%[\w.-]+) = \S+ (gather|scatter)\((%[\w.-]+)"
+                     r".*?op_name=\"([^\"]*)\"", text)
+    assert len(ops) == len(re.findall(r" (?:gather|scatter)\(", text))
+    wire = f"u32[{batch},{cfg.num_mcs},{t},{cfg.lanes + 1}]"
+    found = [(kind, shapes[operand], op_name)
+             for _, kind, operand, op_name in ops]
+    assert [f[:2] for f in found] == [("gather", wire)], found
 
 
 def test_chain_stack_compiles_for_v5e(one_chip):
