@@ -7,17 +7,24 @@ weights (``weights/<model>.npz``), the mesh and the wire precision; the traffic 
 fixes the sweep axes (MC placements, packet->MC affinities, orderings)
 and how the warm-up drains. Adding a cell adds files; nothing here names
 a cell.
+
+A configuration whose operands or reference the defaults cannot make
+names its own harness module, ``"harness": "<stem>"``, which is
+``harness/<stem>.py`` beside this file (see :func:`harness`).
 """
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
-from typing import List, Tuple
+from types import ModuleType
+from typing import Callable, NamedTuple, Sequence, Tuple
 
 import numpy as np
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
+HARNESS = os.path.join(BENCH, "harness")
 
 
 def manifest() -> dict:
@@ -76,5 +83,45 @@ def cell_layers(config: dict, seed: int):
     return [LayerTraffic(jnp.asarray(i), jnp.asarray(w)) for i, w in host], host
 
 
-def layer_shapes(host: List[Tuple[np.ndarray, np.ndarray]]):
-    return [tuple(i.shape) for i, _ in host]
+def layer_shapes(host: Sequence[Tuple[np.ndarray, ...]]):
+    return [tuple(layer[0].shape) for layer in host]
+
+
+def load_module(path: str, name: str) -> ModuleType:
+    """The Python file at ``path``, loaded as module ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+class Harness(NamedTuple):
+    """What a run and the control take from a configuration.
+
+    - ``cell_layers(config, seed) -> (program_layers, host_layers)``: the
+      operand traffic the program sweeps, and the same operands on the host
+      for the reference.
+    - ``reference_rows(host_layers, config, traffic, bits=None, log=None)``:
+      the plain reference's rows, in the sweep's row order.
+
+    A host layer is ``(inputs, weights)`` or ``(inputs, weights,
+    pe_index)``; ``reference.reference_rows`` says what the index means.
+    """
+    cell_layers: Callable
+    reference_rows: Callable
+
+
+def harness(config: dict) -> Harness:
+    """The configuration's harness: ``harness/<stem>.py`` where it names
+    one under ``"harness"``, else :func:`cell_layers` and
+    ``reference.reference_rows``. A named module exports both functions."""
+    stem = config.get("harness")
+    if stem is None:
+        from reference import reference_rows
+        return Harness(cell_layers, reference_rows)
+    mod = load_module(os.path.join(HARNESS, f"{stem}.py"), f"harness_{stem}")
+    missing = [f for f in Harness._fields
+               if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(f"harness {stem!r} does not export {missing}")
+    return Harness(mod.cell_layers, mod.reference_rows)

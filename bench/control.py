@@ -25,14 +25,14 @@ sys.path.insert(0, BENCH)
 
 import cell  # noqa: E402
 import check  # noqa: E402
-from operands import cell_operands  # noqa: E402
-from reference import reference_rows  # noqa: E402
 
 CONTROL_BITS = 4
 
 
 def control_numbers(host_layers, config, traffic):
-    """``check.compare`` of the fixed-4 reference against the fixed-8 one."""
+    """``check.compare`` of the fixed-4 reference against the fixed-8 one,
+    both by the configuration's harness (``cell.harness``)."""
+    reference_rows = cell.harness(config).reference_rows
     ref = reference_rows(host_layers, config, traffic)
     ctl = reference_rows(host_layers, config, traffic, bits=CONTROL_BITS)
     numbers, _due, _wrong = check.compare([ctl], ref)
@@ -47,7 +47,7 @@ def main(argv=None) -> int:
     _entry, config, traffic = cell.workload(args.workload)
     for seed in args.seeds:
         t0 = time.perf_counter()
-        host = cell_operands(config, seed)
+        _prog, host = cell.harness(config).cell_layers(config, seed)
         numbers = control_numbers(host, config, traffic)
         print(json.dumps({"workload": args.workload, "seed": seed,
                           "control_correct": check.passed(numbers),

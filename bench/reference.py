@@ -247,22 +247,23 @@ def mc_table(rows: int, cols: int, mcs, pes, affinity: str):
 class Streams:
     """Per-MC injection streams of one (placement, affinity) layout.
 
-    Packet g (numbered across layers) goes to PE ``pes[g % len(pes)]``, is
-    served by MC ``g % M`` (or the affinity table's MC for that PE), rides VC
-    ``(earlier packets at its MC) % num_vcs``, and is one header flit
-    (words: dest, g, payload flits) followed by its payload flits, in packet
-    order within each MC's stream.
+    Packet g (numbered across layers) goes to PE ``pes[pe_index[g]]``
+    (see :func:`pe_indices`), is served by MC ``g % M`` (or the affinity
+    table's MC for that PE), rides VC ``(earlier packets at its MC) %
+    num_vcs``, and is one header flit (words: dest, g, payload flits)
+    followed by its payload flits, in packet order within each MC's stream.
     """
 
     def __init__(self, shapes: Sequence[Tuple[int, int]], rows: int,
-                 cols: int, mcs, num_vcs: int, lanes: int, table=None):
+                 cols: int, mcs, num_vcs: int, lanes: int,
+                 pe_index: np.ndarray, table=None):
         pes = [x for x in range(rows * cols) if x not in set(mcs)]
         m = len(mcs)
         npk = [n for n, _ in shapes]
         g = np.arange(sum(npk), dtype=np.int64)
         fl = np.concatenate([np.full(n, f + 1, np.int64)
                              for n, f in shapes]) if npk else g
-        mc = g % m if table is None else table[g % len(pes)]
+        mc = g % m if table is None else table[pe_index]
         # Stable sort by MC keeps packet order inside each stream.
         by_mc = np.argsort(mc, kind="stable")
         before = np.empty_like(g)
@@ -271,7 +272,7 @@ class Streams:
         before[by_mc] = np.arange(g.size) - np.repeat(starts, counts)
         self.m, self.lanes = m, lanes
         self.mc_nodes = np.asarray(mcs, np.int64)
-        self.pkt_dest = np.asarray(pes, np.int64)[g % len(pes)]
+        self.pkt_dest = np.asarray(pes, np.int64)[pe_index]
         self.pkt_vc = before % num_vcs
         self.pkt_flits = fl
         # Global flit numbering: stream s's flits are consecutive from
@@ -422,13 +423,40 @@ def total_bt(st: Streams, words: np.ndarray, seq: np.ndarray,
 
 # --- one cell ---------------------------------------------------------------------
 
-def reference_rows(layers: Sequence[Tuple[np.ndarray, np.ndarray]],
+def pe_indices(layers: Sequence[Tuple[np.ndarray, ...]],
+               num_pes: int) -> np.ndarray:
+    """Every packet's PE index, across layers: a layer's own ``pe_index``
+    where it gives one, else ``g % num_pes`` of the global packet id g."""
+    out, g0 = [np.zeros(0, np.int64)], 0
+    for layer in layers:
+        n = layer[0].shape[0]
+        own = (np.asarray(layer[2], np.int64) if len(layer) == 3
+               else (g0 + np.arange(n, dtype=np.int64)) % num_pes)
+        if own.shape != (n,):
+            raise ValueError(f"a layer of {n} packets has a pe_index of "
+                             f"shape {own.shape}")
+        if n and (own.min() < 0 or own.max() >= num_pes):
+            raise IndexError(f"pe_index outside [0, {num_pes}): "
+                             f"{own.min()}..{own.max()}")
+        out.append(own)
+        g0 += n
+    return np.concatenate(out)
+
+
+def reference_rows(layers: Sequence[Tuple[np.ndarray, ...]],
                    config: dict, traffic: dict, bits: Optional[int] = None,
                    log=None) -> List[Dict]:
     """Rows ``{placement, affinity, transform, total_bt, cycles, flits}``
     for every (placement, affinity, transform) of the cell, in the sweep's
     row order. ``bits`` overrides the configuration's fixed-point width
-    (the control computes at a narrower one)."""
+    (the control computes at a narrower one).
+
+    A layer is ``(inputs, weights)`` or ``(inputs, weights, pe_index)``:
+    ``pe_index`` is an int array, one entry per packet (row), that picks
+    the packet's destination from the placement's PE list (the mesh's
+    routers without an MC, in router order), so that it holds under every
+    placement. A layer without one deals its packets round-robin, packet g
+    to index ``g % len(pes)``."""
     noc = config["noc"]
     rows, cols, nmc = noc["rows"], noc["cols"], noc["num_mcs"]
     lanes, nvc, depth = noc["lanes"], noc["num_vcs"], noc["vc_depth"]
@@ -436,7 +464,7 @@ def reference_rows(layers: Sequence[Tuple[np.ndarray, np.ndarray]],
         raise KeyError(f"the reference has no precision {config['precision']!r}")
     bits = 8 if bits is None else bits
     quant = [(quantize_fixed(i, bits), quantize_fixed(w, bits))
-             for i, w in layers]
+             for i, w, *_ in layers]
     half = lanes // 2
     axes = traffic["grid"]
     if any(tb != "pattern" for tb in axes["tiebreaks"]):
@@ -454,6 +482,7 @@ def reference_rows(layers: Sequence[Tuple[np.ndarray, np.ndarray]],
         pes = [x for x in range(rows * cols) if x not in set(mcs)]
         for aff in axes["affinity"]:
             st = Streams(shapes, rows, cols, mcs, nvc, lanes,
+                         pe_indices(layers, len(pes)),
                          mc_table(rows, cols, mcs, pes, aff))
             cycles, links, flits = drain_schedule(st, rows, cols, nvc, depth)
             if log:

@@ -2,16 +2,17 @@
 
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-A run makes the input image from ``--seed`` and the layer operands of one
-inference on it (``operands.py``, from the weights kept beside the
-benchmark), warms up every program the window will use (set-up), then
-drives ``repro.noc.run_sweep`` in whole sweeps for ``--seconds``. With
+A run makes the layer operands of one inference from ``--seed`` (by
+default ``operands.py``, on the seed's image with the weights kept beside
+the benchmark; a configuration may name its own harness module, see
+``cell.harness``), warms up every program the window will use (set-up),
+then drives ``repro.noc.run_sweep`` in whole sweeps for ``--seconds``. With
 ``--trace 1`` one more whole sweep follows the window under the profiler.
 Afterwards every row of every sweep is compared with the plain reference
-(``reference.py``), and the last line of standard output is one JSON
-object: ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``,
-with ``--trace 1`` also ``breakdown``, and last the compared numbers beside
-their limits under ``check``.
+(by default ``reference.py``), and the last line of standard output is one
+JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``,
+``device``, with ``--trace 1`` also ``breakdown``, and last the compared
+numbers beside their limits under ``check``.
 
 ``--trace 0`` reports the cell's end-to-end metrics and ``--trace 1`` its
 per-layer metrics, each read by ``metrics/<name>.py``: the host-clock spans
@@ -26,7 +27,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -113,10 +113,7 @@ def metric_reader(name: str) -> Callable:
     path = os.path.join(BENCH, "metrics", f"{name}.py")
     if not os.path.exists(path) and "." in name:
         path = os.path.join(BENCH, "metrics", f"{name.split('.')[0]}.py")
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return cells.load_module(path, f"metric_{name}").read
 
 
 def cell_metrics(name: str, trace: bool) -> List[dict]:
@@ -214,15 +211,16 @@ def run_cell(name: str, config: dict, traffic: dict, seed: int,
              seconds: float, trace: bool, devices, layers=None,
              lines=tracefile.tpu_lines) -> Dict:
     """Set-up, window, check and metrics of one run; returns the result
-    object. ``layers`` (program layers, host layers) stands in for the
-    configuration's operands; ``lines`` tells the trace reduction where the
-    device events are."""
+    object. The configuration's harness (``cell.harness``) makes the
+    operands and the reference rows; ``layers`` (program layers, host
+    layers) stands in for its operands. ``lines`` tells the trace reduction
+    where the device events are."""
     import jax
-    from reference import reference_rows
     compile_cache()
     counter = CompileCounter()
+    harness = cells.harness(config)
     if layers is None:
-        layers = cells.cell_layers(config, seed)
+        layers = harness.cell_layers(config, seed)
         log(f"operands made: {cells.layer_shapes(layers[1])}")
     prog_layers, host_layers = layers
     devs = "auto" if len(devices) == len(jax.devices()) else list(devices)
@@ -243,10 +241,11 @@ def run_cell(name: str, config: dict, traffic: dict, seed: int,
         log(f"trace: {span_s:.3f} s traced, {(hi - lo) * 1e-9:.3f} s kept")
     peak = peak_bytes(devices)
 
-    ref = reference_rows(host_layers, config, traffic, log=log)
+    ref = harness.reference_rows(host_layers, config, traffic, log=log)
     numbers, due, wrong = check.compare(
         [s["rows"] for s in run.sweeps + run.traced]
         + [[]] * run.failed_sweeps, ref)
+    log(f"reference and check done: {due} rows due, {wrong} wrong")
     metrics = {}
     for m in cell_metrics(name, trace):
         value = metric_reader(m["name"])(run)

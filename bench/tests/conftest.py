@@ -16,6 +16,12 @@ for p in (os.path.join(ROOT, "src"), BENCH):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+# Harness modules of the tests (``harness/<stem>.py`` here), and what the
+# tiny one was called for.
+TEST_HARNESS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                            "harness")
+HARNESS_CALLS = []
+
 TINY_CONFIG = {"model": "tiny", "precision": "fixed8",
                "noc": {"rows": 4, "cols": 4, "num_mcs": 2, "num_vcs": 4,
                        "vc_depth": 4, "lanes": 16}}

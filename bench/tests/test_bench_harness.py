@@ -13,8 +13,8 @@ import types
 
 import pytest
 
-from conftest import (BENCH, ROOT, TINY_CONFIG, cpu_lines, tiny_layers,
-                      tiny_traffic)
+from conftest import (BENCH, HARNESS_CALLS, ROOT, TEST_HARNESS, TINY_CONFIG,
+                      cpu_lines, tiny_layers, tiny_traffic)
 
 import cell
 import run as bench_run
@@ -73,6 +73,9 @@ def test_manifest_files_exist(manifest):
     for c in manifest["configs"]:
         assert c["file"] == f"bench/configs/{c['name']}.json"
         assert os.path.isfile(os.path.join(ROOT, c["file"]))
+        # a named harness module exists and exports both functions, or
+        # cell.harness raises
+        cell.harness(cell.load_json("configs", c["name"]))
     for w in manifest["workloads"]:
         _, config, traffic = cell.workload(w["name"])
         assert config["name"] == w["config"]
@@ -290,3 +293,61 @@ def test_traced_run_on_a_tiny_cell(tiny):
     # the traced sweep lies inside the traced window, so its gaps are named
     assert any(label.startswith("sweep 0:")
                for label, _ in res["breakdown"]["idle_gaps"])
+
+
+def test_harness_lookup_refuses_a_missing_or_partial_module(tmp_path,
+                                                            monkeypatch):
+    monkeypatch.setattr(cell, "HARNESS", str(tmp_path))
+    with pytest.raises(FileNotFoundError):
+        cell.harness({"harness": "absent"})
+    (tmp_path / "partial.py").write_text("def cell_layers(config, seed):\n"
+                                         "    return [], []\n")
+    with pytest.raises(AttributeError, match="reference_rows"):
+        cell.harness({"harness": "partial"})
+    from reference import reference_rows
+    assert cell.harness(TINY_CONFIG) == (cell.cell_layers, reference_rows)
+
+
+def test_a_configuration_runs_its_own_harness_module(monkeypatch, capsys):
+    """A configuration with ``"harness"`` runs through ``run_cell`` and the
+    control on that module's operands and reference, with no other edit,
+    and its run reads as the default path's run on the same operands."""
+    import jax
+    import check
+    import control
+    monkeypatch.setattr(cell, "HARNESS", TEST_HARNESS)
+    seen = []
+    real_compare = check.compare
+
+    def compare(sweeps, reference):
+        seen.append(([{k: r[k] for k in check.KEYS + check.FIELDS}
+                      for r in sweeps[0]], reference))
+        return real_compare(sweeps, reference)
+
+    monkeypatch.setattr(check, "compare", compare)
+    traffic = tiny_traffic()
+    default = bench_run.run_cell("tiny", TINY_CONFIG, traffic, 5, 0.5, False,
+                                 jax.devices(), layers=tiny_layers(5))
+    HARNESS_CALLS.clear()
+    config = dict(TINY_CONFIG, harness="tiny")
+    named = bench_run.run_cell("tiny", config, traffic, 5, 0.5, False,
+                               jax.devices())
+    assert HARNESS_CALLS == ["cell_layers", "reference_rows"]
+    assert seen[1] == seen[0]
+    assert named["correct"] and named["failed"] == default["failed"] == 0
+    assert named["check"] == default["check"]
+    assert list(named) == list(default)
+    assert set(named["metrics"]) == set(default["metrics"])
+    assert set(named["device"]) == set(default["device"])
+    assert named["attempted"] % 3 == 0 and named["attempted"] >= 3
+
+    monkeypatch.setattr(check, "compare", real_compare)
+    HARNESS_CALLS.clear()
+    monkeypatch.setattr(cell, "workload", lambda name: ({}, config, traffic))
+    capsys.readouterr()
+    assert control.main(["--workload", "tiny", "--seeds", "3"]) == 0
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert HARNESS_CALLS == ["cell_layers", "reference_rows",
+                             "reference_rows"]
+    assert out["control_correct"] is False
+    assert out["check"]["bt_rows_differing"]["value"] == 3
